@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -28,7 +28,7 @@ from typing import Sequence, Tuple
 
 import jax
 
-from repro.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 

@@ -203,7 +203,6 @@ class RunConfig:
     grad_allreduce: str = "auto"  # auto | flat | hierarchical (multi-pod)
     moe_alltoall: str = "auto"  # auto | direct | hierarchical
     grad_compression: str = "none"  # none | int8
-    use_pallas: bool = False  # Pallas kernels (TPU); jnp reference path on CPU
     # fault tolerance
     checkpoint_every: int = 100
     checkpoint_dir: str = "/tmp/repro_ckpt"

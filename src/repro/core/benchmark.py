@@ -1,9 +1,9 @@
 """Live microbenchmarks + model fitting (the paper's measurement pipeline).
 
-On real TPU/GPU hardware these functions measure the actual transport tiers;
-in this container they exercise the identical code path against host-level
-transfers (device_put round-trips and jitted collectives on CPU devices), so
-the fit -> model -> plan pipeline is tested end-to-end.
+The transfer benchmarks time real transports of the devices JAX was given
+(host<->device copies, device<->device sends) and fit postal models to
+them.  Each sample is a host-clock time around work that ends in a
+blocking wait; the device the samples came from is the caller's to record.
 
 :func:`spec_from_measurements` closes the loop the paper draws in §VI:
 measured tiers become a registered :class:`~repro.core.machine.MachineSpec`,
@@ -88,45 +88,61 @@ def bench_host_device_roundtrip(sizes: Sequence[int] = (1 << 12, 1 << 16, 1 << 2
     return bench_transfer(make, put, sizes)
 
 
-def bench_jitted_allreduce(
-    n_devices: int, sizes: Sequence[int] = (1 << 12, 1 << 16, 1 << 20)
-) -> Dict[str, BenchResult]:
-    """Time flat psum vs hierarchical reduce on an n_devices CPU mesh.
+def bench_device_to_host(
+    sizes: Sequence[int] = (1 << 12, 1 << 16, 1 << 20, 1 << 23), samples: int = 20
+) -> BenchResult:
+    """Device jax buffer -> host numpy: the min over ``samples`` copies.
 
-    Requires the process to have been started with
-    XLA_FLAGS=--xla_force_host_platform_device_count=<n_devices>.
+    Not :func:`bench_transfer`'s repeat loop: a ``jax.Array`` keeps its host
+    copy after the first read, so every sample reads a buffer of its own,
+    put on the device outside the timed region.
     """
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from repro.compat import shard_map
+    measured: List[float] = []
+    for s in sizes:
+        best = float("inf")
+        for _ in range(samples):
+            buf = jax.device_put(np.zeros(s, np.uint8)).block_until_ready()
+            t0 = time.perf_counter()
+            jax.device_get(buf)
+            best = min(best, time.perf_counter() - t0)
+        measured.append(best)
+    return BenchResult(sizes=list(sizes), times=measured, fitted=fit_postal(sizes, measured))
 
-    devs = jax.devices()
-    if len(devs) < n_devices:
-        raise RuntimeError(f"need {n_devices} devices, have {len(devs)}")
-    mesh = Mesh(np.asarray(devs[:n_devices]).reshape(n_devices), ("x",))
 
-    results: Dict[str, BenchResult] = {}
+def _pingpong(a, b, hop: Callable[[object, object], object],
+              sizes: Sequence[int]) -> BenchResult:
+    """One-way time between devices ``a`` and ``b``: half a round trip."""
+    import jax
 
-    def run(sum_fn, name):
-        def make(s: int):
-            arr = jnp.zeros((n_devices, max(s // 4, 1)), jnp.float32)
-            return jax.device_put(arr, NamedSharding(mesh, P("x", None)))
+    def make(s: int):
+        return [jax.device_put(np.zeros(s, np.uint8), a)]
 
-        def go(buf):
-            sum_fn(buf).block_until_ready()
+    def there_and_back(held):
+        held[0] = hop(hop(held[0], b), a).block_until_ready()
 
-        results[name] = bench_transfer(make, go, sizes)
+    rt = bench_transfer(make, there_and_back, sizes)
+    one_way = [t / 2 for t in rt.times]
+    return BenchResult(sizes=rt.sizes, times=one_way, fitted=fit_postal(rt.sizes, one_way))
 
-    @jax.jit
-    def psum_all(x):
-        return shard_map(
-            lambda v: jax.lax.psum(v, "x"), mesh=mesh, in_specs=P("x", None), out_specs=P(None, None)
-        )(x)
 
-    run(psum_all, "allreduce_flat")
-    return results
+def bench_device_pingpong(
+    a, b, sizes: Sequence[int] = (1 << 12, 1 << 16, 1 << 20, 1 << 23)
+) -> BenchResult:
+    """Direct device-to-device sends (the GPUDirect analogue)."""
+    import jax
+
+    return _pingpong(a, b, lambda x, dev: jax.device_put(x, dev), sizes)
+
+
+def bench_staged_pingpong(
+    a, b, sizes: Sequence[int] = (1 << 12, 1 << 16, 1 << 20, 1 << 23)
+) -> BenchResult:
+    """Sends staged through host memory: device -> host, host -> device."""
+    import jax
+
+    return _pingpong(a, b, lambda x, dev: jax.device_put(np.asarray(x), dev), sizes)
 
 
 # --------------------------------------------------------------------------

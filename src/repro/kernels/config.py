@@ -1,32 +1,49 @@
 """Kernel dispatch switch.
 
-``use_pallas(True)`` routes model hot-spots (attention, WKV6, RG-LRU scan)
-through the Pallas TPU kernels; default False keeps the pure-XLA path (the
-one the dry-run lowers — TPU-kernel HLO must not block the CPU compile).
-On CPU backends the kernels run in interpret mode automatically (tests).
+Inside ``with use_pallas(True):`` the model hot-spots (attention, WKV6,
+RG-LRU scan) trace through the Pallas TPU kernels; outside it they trace
+the pure-XLA path (the one the dry-run lowers).  The switch is read while
+a function is *traced*, so it has to be held around the first call of a
+jitted step, not only around ``jax.jit``.  Each entry point holds its own
+setting for its whole run (``launch/serve.py``, ``launch/train.py``), so
+nothing one run chose leaks into the next run in the same process.
+
+On CPU backends the kernels run in interpret mode (tests).
 """
+import contextlib
+import contextvars
+import logging
+
 import jax
 
-_USE_PALLAS = False
+from repro.obs import metrics
+
+_USE_PALLAS = contextvars.ContextVar("repro_use_pallas", default=False)
+
+log = logging.getLogger(__name__)
 
 
-def use_pallas(on: bool) -> None:
-    global _USE_PALLAS
-    _USE_PALLAS = on
+@contextlib.contextmanager
+def use_pallas(on: bool):
+    """Trace the model hot-spots through the Pallas kernels inside the block."""
+    token = _USE_PALLAS.set(on)
+    try:
+        yield
+    finally:
+        _USE_PALLAS.reset(token)
 
 
 def pallas_enabled() -> bool:
-    return _USE_PALLAS
+    return _USE_PALLAS.get()
 
 
 def interpret_mode() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def tpu_compiler_params(**kwargs):
-    """Version-portable pltpu compiler params (renamed across jax releases:
-    ``TPUCompilerParams`` on jax<=0.4.x, ``CompilerParams`` afterwards)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+def fallback(kernel: str, reason: str) -> None:
+    """An enabled kernel cannot take this shape: say so, and count it as
+    ``kernels.fallback.<kernel>``, before the caller traces the reference."""
+    metrics.inc(f"kernels.fallback.{kernel}")
+    log.warning("Pallas %s kernel cannot take %s; tracing the reference path",
+                kernel, reason)

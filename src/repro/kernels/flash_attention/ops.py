@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.kernels.config import interpret_mode
+from repro.kernels import config
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -23,19 +23,20 @@ def attention(
     window: int = 0,
     q_offset: int = 0,
     softcap: float = 0.0,
-    use_kernel: bool = True,
     block: int = 128,
 ) -> jax.Array:
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    if use_kernel and supported(q.shape[1], k.shape[1], q.shape[-1], block):
+    if supported(q.shape[1], k.shape[1], q.shape[-1], block):
         out = flash_attention(
             qt, kt, vt,
             causal=causal, window=window, q_offset=q_offset, softcap=softcap,
-            block_q=block, block_k=block, interpret=interpret_mode(),
+            block_q=block, block_k=block, interpret=config.interpret_mode(),
         )
     else:
+        config.fallback("flash_attention", f"Sq={q.shape[1]}, Sk={k.shape[1]}, "
+                                           f"dh={q.shape[-1]}")
         out = attention_ref(
             qt, kt, vt, causal=causal, window=window, q_offset=q_offset,
             softcap=softcap,

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.config import tpu_compiler_params
-
 
 def _kernel(a_ref, b_ref, y_ref, h_scr, *, chunk: int, chunks: int):
     ci = pl.program_id(2)
@@ -74,7 +72,7 @@ def rglru_scan(
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(a, b)

@@ -1,8 +1,9 @@
 """WKV6 chunk-parallel scan as a Pallas TPU kernel.
 
 TPU-native design: one grid cell per (batch*head, chunk) with the chunk
-axis *sequential* ("arbitrary") so the (K x V) state matrix persists in
-VMEM scratch across chunks — zero HBM state traffic, versus the pure-XLA
+axis *sequential* ("arbitrary") so the state matrix persists in VMEM
+scratch across chunks (held transposed, (V x K), so that its per-key
+decay is a row broadcast) — zero HBM state traffic, versus the pure-XLA
 chunked scan whose carried state round-trips HBM every chunk.  Within a
 chunk everything is dense (L x L x K pairwise-decay einsum feeding the
 MXU), the same algebra as models/rwkv.wkv_chunked; all decay exponents are
@@ -20,13 +21,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.config import tpu_compiler_params
-
 
 def _kernel(
-    r_ref, k_ref, v_ref, lw_ref, u_ref,  # (1, L, K) x4, (1, K)
-    y_ref, fin_ref,  # (1, L, K), (1, K, K)
-    state_scr,  # VMEM (K, K) f32
+    r_ref, k_ref, v_ref, lw_ref, u_ref,  # (1, L, K) x4, (1, 1, K)
+    y_ref, fin_ref,  # (1, L, K), (1, V, K)
+    state_scr,  # VMEM (V, K) f32: the state, transposed
     *,
     chunks: int,
     chunk: int,
@@ -41,10 +40,16 @@ def _kernel(
     kk = k_ref[0].astype(jnp.float32)
     vv = v_ref[0].astype(jnp.float32)
     lw = lw_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)  # (K,)
+    u = u_ref[0].astype(jnp.float32)  # (1, K)
     L = rr.shape[0]
 
-    cum = jnp.cumsum(lw, axis=0)  # (L, K)
+    # inclusive prefix sum over the chunk by log2(L) shifted adds (Mosaic
+    # has no cumsum lowering)
+    cum = lw  # (L, K)
+    s = 1
+    while s < L:
+        cum = cum + jnp.concatenate([jnp.zeros((s, cum.shape[1]), cum.dtype), cum[:-s]], axis=0)
+        s *= 2
     cum_ex = cum - lw
     # intra-chunk pairwise decays: exp(cum_ex[t] - cum[s]) for s < t
     D = cum_ex[:, None, :] - cum[None, :, :]  # (L, L, K)
@@ -57,19 +62,19 @@ def _kernel(
         att, vv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     # diagonal bonus term
-    y += (rr * u[None] * kk).sum(-1, keepdims=True) * vv
+    y += (rr * u * kk).sum(-1, keepdims=True) * vv
     # cross-chunk state contribution
     y += jax.lax.dot_general(
         rr * jnp.exp(cum_ex), state_scr[...],
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
     )
     y_ref[0] = y.astype(y_ref.dtype)
 
-    # state update: S' = exp(cum_L) * S + sum_s exp(cum_L - cum_s) k_s v_s^T
-    A_L = jnp.exp(cum[-1])  # (K,)
-    decay_to_end = jnp.exp(cum[-1][None, :] - cum)  # (L, K)
-    state_scr[...] = A_L[:, None] * state_scr[...] + jax.lax.dot_general(
-        (kk * decay_to_end), vv, (((0,), (0,)), ((), ())),
+    # state update, transposed: S'^T = S^T exp(cum_L) + sum_s v_s (exp(cum_L - cum_s) k_s)^T
+    cum_last = cum[L - 1:]  # (1, K)
+    decay_to_end = jnp.exp(cum_last - cum)  # (L, K)
+    state_scr[...] = jnp.exp(cum_last) * state_scr[...] + jax.lax.dot_general(
+        vv, (kk * decay_to_end), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -107,7 +112,9 @@ def wkv6(
             pl.BlockSpec((1, chunk, K), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, K), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, K), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, K), lambda b, c, _h=H: (b % _h, 0)),
+            # u as (H, 1, K): a block's last two dims must equal the
+            # array's (or be (8, 128)-aligned), which (1, K) of (H, K) is not
+            pl.BlockSpec((1, 1, K), lambda b, c, _h=H: (b % _h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, K), lambda b, c: (b, c, 0)),
@@ -119,11 +126,11 @@ def wkv6(
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
-    )(rs, ks, vs, ws, u)
+    )(rs, ks, vs, ws, u.reshape(H, 1, K))
 
     y = y.reshape(B, H, S, K).transpose(0, 2, 1, 3)
-    fin = fin.reshape(B, H, K, K)
+    fin = fin.reshape(B, H, K, K).swapaxes(-1, -2)
     return y, fin

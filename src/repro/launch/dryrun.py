@@ -246,8 +246,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, variant: dict, outdir: 
                 "generated_code_bytes": ma.generated_code_size_in_bytes,
             }
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per program
-                ca = ca[0] if ca else {}
             rec["cost"] = {
                 "flops": ca.get("flops", 0.0),
                 "bytes_accessed": ca.get("bytes accessed", 0.0),

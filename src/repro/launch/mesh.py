@@ -9,27 +9,17 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax (e.g. 0.4.x): meshes are implicitly "auto"
-    AxisType = None
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axes(mesh) -> dict:

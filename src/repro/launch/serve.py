@@ -14,6 +14,7 @@ place of the old ad-hoc cache print.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import time
 
@@ -22,13 +23,27 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config, smoke_config
+from repro.kernels import use_pallas
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import dp_axes_of
-from repro.launch.train import build_mesh
+from repro.launch.train import build_mesh, init_sharded_params
 from repro.models import decode as dec
-from repro.models import init_params
 from repro.models.transformer import DistContext
 from repro.obs import drift, health, metrics, trace
 from repro.sharding import specs
+
+
+@dataclasses.dataclass
+class ServeResult:
+    tokens: np.ndarray  # (B, N) generated ids; -1 after a sequence was shed
+    prefill_logits: jax.Array  # (B, V) logits at the last prompt position
+    prefill: jax.stages.Compiled  # the prefill program that produced them
+
+
+def make_prompts(rng: np.random.Generator, vocab_size: int, batch: int,
+                 prompt_len: int) -> np.ndarray:
+    """Prompt ids in [2, vocab), the first draw of the run's seeded rng."""
+    return rng.integers(2, vocab_size, size=(batch, prompt_len), dtype=np.int32)
 
 
 def main(argv=None):
@@ -90,10 +105,19 @@ def main(argv=None):
     )
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
+    mesh = build_mesh(args.mesh_shape)
+    # The kernels are compiled for the TPU only (elsewhere they would run
+    # interpreted, which no deployment does), and a Mosaic kernel cannot be
+    # partitioned over a mesh by the compiler.
+    with use_pallas(jax.default_backend() == "tpu" and mesh.size == 1):
+        return _serve(args, mesh)
+
+
+def _serve(args, mesh) -> ServeResult:
     metrics.enable()
     tracer = trace.start(name="serve") if args.trace else None
 
-    mesh = build_mesh(args.mesh_shape)
     tp = mesh.shape.get("model", 1)
     cfg0 = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg, ep_shards = specs.tp_adapt(cfg0, tp)
@@ -103,11 +127,11 @@ def main(argv=None):
         else None
     )
 
-    params = init_params(cfg, jax.random.PRNGKey(args.seed), ep_shards=ep_shards)
+    params = init_sharded_params(cfg, mesh, ep_shards=ep_shards, seed=args.seed)
     B, P_len, N = args.batch, args.prompt_len, args.new_tokens
     capacity = P_len + N
     rng = np.random.default_rng(args.seed)
-    prompts = rng.integers(2, cfg.vocab_size, size=(B, P_len), dtype=np.int32)
+    prompts = jnp.asarray(make_prompts(rng, cfg.vocab_size, B, P_len))
     frontend = None
     if cfg.frontend_tokens:
         fd = cfg.frontend_dim or cfg.d_model
@@ -117,17 +141,20 @@ def main(argv=None):
         )
 
     t0 = time.perf_counter()
-    with trace.span("prefill", batch=B, prompt_len=P_len):
+    with trace.span("prefill.compile", batch=B, prompt_len=P_len):
         prefill_fn = jax.jit(
-            functools.partial(dec.prefill, cfg, capacity=capacity, dist=dist),
-            static_argnames=(),
-        )
-        logits, caches = prefill_fn(params, jnp.asarray(prompts), frontend=frontend)
+            functools.partial(dec.prefill, cfg, capacity=capacity, dist=dist)
+        ).lower(params, prompts, frontend=frontend).compile()
+    t_compile = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    with trace.span("prefill", batch=B, prompt_len=P_len):
+        logits, caches = prefill_fn(params, prompts, frontend=frontend)
         logits.block_until_ready()
     t_prefill = time.perf_counter() - t0
+    prefill_logits = logits
     metrics.observe("serve.prefill.seconds", t_prefill)
     print(f"[serve] prefill {B}x{P_len} in {t_prefill:.2f}s "
-          f"({B * P_len / t_prefill:.0f} tok/s)")
+          f"({B * P_len / t_prefill:.0f} tok/s), compiled in {t_compile:.2f}s")
 
     decode_fn = jax.jit(
         functools.partial(dec.decode_step, cfg, dist=dist),
@@ -314,8 +341,8 @@ def main(argv=None):
     print("[serve] metrics:",
           metrics.summary_line(prefixes=["serve.", "plan_cache.",
                                          "lowering_memo.", "engine.",
-                                         "health.", "runtime."]))
-    return gen
+                                         "health.", "runtime.", "kernels."]))
+    return ServeResult(tokens=gen, prefill_logits=prefill_logits, prefill=prefill_fn)
 
 
 if __name__ == "__main__":

@@ -22,6 +22,8 @@ from repro.checkpoint import Checkpointer
 from repro.configs import ARCHS, get_config, smoke_config
 from repro.configs.base import RunConfig
 from repro.data import SyntheticLM
+from repro.kernels import use_pallas
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import dp_axes_of, make_mesh
 from repro.models import init_params
 from repro.models.steps import train_step
@@ -41,7 +43,17 @@ def build_mesh(arg: str):
     return make_mesh(dims, names)
 
 
+def init_sharded_params(cfg, mesh, *, ep_shards: int, seed: int):
+    """Seeded params; on a mesh of several devices each leaf is created in
+    place under ``specs.param_shardings`` (no full copy on one device)."""
+    init = functools.partial(init_params, cfg, ep_shards=ep_shards)
+    key = jax.random.PRNGKey(seed)
+    p_sh = specs.param_shardings(jax.eval_shape(init, key), mesh) if mesh.size > 1 else None
+    return jax.jit(init, out_shardings=p_sh)(key)
+
+
 def main(argv=None):
+    """Train; returns the loss of every step this run took."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b", choices=sorted(ARCHS))
     ap.add_argument("--smoke", action="store_true", help="reduced config")
@@ -61,6 +73,14 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=1)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
+    # The Pallas kernels have no backward pass, so training traces the jnp
+    # path whatever setting the caller holds.
+    with use_pallas(False):
+        return _train(args)
+
+
+def _train(args):
     mesh = build_mesh(args.mesh_shape)
     tp = mesh.shape.get("model", 1)
     cfg0 = smoke_config(args.arch) if args.smoke else get_config(args.arch)
@@ -81,16 +101,7 @@ def main(argv=None):
         total_steps=args.total_steps or args.steps,
     )
 
-    p_sh = specs.param_shardings(
-        jax.eval_shape(functools.partial(init_params, cfg, ep_shards=ep_shards),
-                       jax.random.PRNGKey(args.seed)),
-        mesh,
-    ) if dist else None
-    init_fn = jax.jit(
-        functools.partial(init_params, cfg, ep_shards=ep_shards),
-        out_shardings=p_sh,
-    )
-    params = init_fn(jax.random.PRNGKey(args.seed))
+    params = init_sharded_params(cfg, mesh, ep_shards=ep_shards, seed=args.seed)
     opt = adamw.init_state(params)
 
     data = SyntheticLM(
@@ -101,7 +112,9 @@ def main(argv=None):
         frontend_tokens=cfg.frontend_tokens,
         frontend_dim=(cfg.frontend_dim or cfg.d_model) if cfg.frontend_tokens else 0,
     )
-    step_fn = jax.jit(functools.partial(train_step, cfg, run, dist=dist))
+    # donated: at full width the params and Adam moments do not fit twice
+    step_fn = jax.jit(functools.partial(train_step, cfg, run, dist=dist),
+                      donate_argnums=(0, 1))
 
     ckpt = Checkpointer(args.checkpoint_dir) if args.checkpoint_dir else None
     start = 0
@@ -113,11 +126,13 @@ def main(argv=None):
 
     mon = StragglerMonitor()
     tokens_per_step = args.batch * args.seq
+    losses = []
     for step in range(start, args.steps):
         t0 = time.perf_counter()
         batch = {k: jax.numpy.asarray(v) for k, v in data.batch(step).items()}
         params, opt, metrics = step_fn(params, opt, batch)
         loss = float(metrics["loss"])
+        losses.append(loss)
         dt = time.perf_counter() - t0
         mon.record(step, dt)
         if step % args.log_every == 0:
@@ -134,7 +149,7 @@ def main(argv=None):
     if ckpt:
         ckpt.save(args.steps, {"params": params, "opt": opt}, block=True)
     print(f"[train] done: final loss {loss:.4f}")
-    return loss
+    return losses
 
 
 if __name__ == "__main__":

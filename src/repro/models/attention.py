@@ -173,12 +173,13 @@ def attend(
     causal: bool = True,
 ) -> jax.Array:
     """Masked attention core; auto-chunks above CHUNK_THRESHOLD keys.
-    Returns (B, Sq, H, dh).  With kernels enabled (repro.kernels.use_pallas)
-    and contiguous positions, dispatches to the Pallas flash kernel."""
-    from repro.kernels import pallas_enabled
+    Returns (B, Sq, H, dh).  Inside ``repro.kernels.use_pallas(True)`` and
+    with contiguous positions, dispatches to the Pallas flash kernel."""
+    from repro.kernels import config as kernels
 
     B, Sq = q.shape[:2]
-    if pallas_enabled() and Sq == k.shape[1]:
+    # Sq != Sk is decode against a cache: the kernel is for full sequences
+    if kernels.pallas_enabled() and Sq == k.shape[1]:
         from repro.kernels.flash_attention import ops as fa_ops
 
         if fa_ops.supported(Sq, k.shape[1], cfg.head_dim_):
@@ -186,6 +187,7 @@ def attend(
                 q, k, v, causal=causal, window=window,
                 softcap=cfg.attn_softcap,
             )
+        kernels.fallback("flash_attention", f"S={Sq}, dh={cfg.head_dim_}")
     qg = _split_groups(cfg, q)
     if k.shape[1] > CHUNK_THRESHOLD:
         out = _attend_chunked(cfg, qg, k, v, q_pos, k_pos, window, causal)

@@ -75,7 +75,8 @@ def _gates(p: dict, u: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def _scan_dispatch(a: jax.Array, gin: jax.Array) -> jax.Array:
-    """Pallas kernel when enabled, else XLA associative_scan."""
+    """Pallas kernel inside ``repro.kernels.use_pallas(True)``, else XLA
+    associative_scan."""
     from repro.kernels import pallas_enabled
 
     if pallas_enabled():

@@ -193,14 +193,16 @@ def _heads(cfg: ModelConfig, a: jax.Array) -> jax.Array:
 
 
 def _wkv_dispatch(rh, kh, vh, lwh, u, chunked: bool, chunk: int = WKV_CHUNK):
-    """Pallas kernel when enabled (repro.kernels.use_pallas), else the
+    """Pallas kernel inside ``repro.kernels.use_pallas(True)``, else the
     pure-XLA chunked scan (the dry-run path) or the recurrence oracle."""
-    from repro.kernels import pallas_enabled
+    from repro.kernels import config as kernels
+    from repro.kernels.rwkv6 import ops as wkv_ops
 
-    if pallas_enabled() and rh.shape[1] % min(chunk, rh.shape[1]) == 0:
-        from repro.kernels.rwkv6 import ops as wkv_ops
-
-        return wkv_ops.wkv(rh, kh, vh, lwh, u, chunk=chunk)
+    S = rh.shape[1]
+    if kernels.pallas_enabled():
+        if wkv_ops.supported(S, chunk):
+            return wkv_ops.wkv(rh, kh, vh, lwh, u, chunk=chunk)
+        kernels.fallback("wkv6", f"S={S} with chunk {chunk}")
     if chunked:
         return wkv_chunked(rh, kh, vh, lwh, u, chunk=chunk)
     return wkv_recurrent(rh, kh, vh, lwh, u)

@@ -19,7 +19,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 
-from repro.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 

@@ -29,7 +29,7 @@ class AdamWConfig:
 
 
 def init_state(params) -> AdamWState:
-    f32_zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+    f32_zeros = lambda p: jnp.zeros_like(p, jnp.float32)  # keeps p's sharding
     return AdamWState(
         step=jnp.zeros((), jnp.int32),
         mu=jax.tree.map(f32_zeros, params),

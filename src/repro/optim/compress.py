@@ -17,7 +17,7 @@ from typing import Sequence, Tuple
 
 import jax
 
-from repro.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 
 BLOCK = 1024  # per-block scales bound quantization error by max|g|_block/127

@@ -15,11 +15,7 @@ import dataclasses
 import numpy as np
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.comms import (
     all_gather_axis,
@@ -39,8 +35,6 @@ ok = lambda name: print(f"OK {name}", flush=True)
 
 
 def mesh2(a, b, names=("pod", "data")):
-    if AxisType is None:
-        return jax.make_mesh((a, b), names)
     return jax.make_mesh((a, b), names, axis_types=(AxisType.Auto,) * 2)
 
 
@@ -198,8 +192,6 @@ def main():
 
     def submesh(a, b, names, devs):
         arr = np.array(devs).reshape(a, b)
-        if AxisType is None:
-            return jax.sharding.Mesh(arr, names)
         return jax.sharding.Mesh(arr, names, axis_types=(AxisType.Auto,) * 2)
 
     def step_on(dist_):

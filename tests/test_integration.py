@@ -1,4 +1,5 @@
 """End-to-end drivers: train loop (with resume), serve loop, dry-run cell."""
+import math
 import os
 import subprocess
 import sys
@@ -13,11 +14,11 @@ SRC = os.path.join(HERE, "..", "src")
 def test_train_driver_loss_decreases():
     from repro.launch.train import main
 
-    loss = main([
+    losses = main([
         "--arch", "olmo-1b", "--smoke", "--steps", "8", "--batch", "4",
         "--seq", "32", "--warmup", "2", "--lr", "3e-3", "--log-every", "4",
     ])
-    assert loss < 6.5  # started ~ ln(512)=6.2+; must have moved down
+    assert losses[-1] < 6.5  # started ~ ln(512)=6.2+; must have moved down
 
 
 def test_train_driver_resume_identical():
@@ -28,7 +29,7 @@ def test_train_driver_resume_identical():
             "--arch", "olmo-1b", "--smoke", "--steps", "6", "--batch", "2",
             "--seq", "32", "--warmup", "1", "--lr", "1e-3",
             "--checkpoint-dir", os.path.join(td, "a"), "--checkpoint-every", "3",
-        ])
+        ])[-1]
     with tempfile.TemporaryDirectory() as td:
         ckdir = os.path.join(td, "b")
         main([
@@ -40,8 +41,23 @@ def test_train_driver_resume_identical():
             "--arch", "olmo-1b", "--smoke", "--steps", "6", "--batch", "2",
             "--seq", "32", "--warmup", "1", "--lr", "1e-3",
             "--checkpoint-dir", ckdir, "--checkpoint-every", "3",
-        ])
+        ])[-1]
     assert resumed == pytest.approx(full, abs=2e-3)
+
+
+def test_train_main_trains_with_kernels_switched_on():
+    """The Pallas kernels have no backward: train.main traces the jnp path
+    even inside a caller's use_pallas(True), and hands the setting back."""
+    from repro.kernels import pallas_enabled, use_pallas
+    from repro.launch.train import main
+
+    with use_pallas(True):
+        losses = main([
+            "--arch", "llama3.2-1b", "--smoke", "--steps", "2", "--batch", "2",
+            "--seq", "32", "--warmup", "1",
+        ])
+        assert pallas_enabled()
+    assert len(losses) == 2 and all(math.isfinite(x) for x in losses)
 
 
 def test_serve_driver_runs():
@@ -50,7 +66,7 @@ def test_serve_driver_runs():
     gen = main([
         "--arch", "llama3.2-1b", "--smoke", "--batch", "2",
         "--prompt-len", "8", "--new-tokens", "4",
-    ])
+    ]).tokens
     assert gen.shape == (2, 4)
     assert (gen >= 0).all()
 

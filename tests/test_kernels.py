@@ -145,3 +145,35 @@ def test_rglru_bf16():
     np.testing.assert_allclose(
         np.asarray(y, np.float32), np.asarray(yr, np.float32), atol=0.15, rtol=0.1
     )
+
+
+# -- dispatch -------------------------------------------------------------------
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "rglru", "wkv6"])
+def test_enabled_kernel_falls_back_visibly(kernel, caplog):
+    """A shape the kernel cannot take runs the reference, with a log line
+    and a kernels.fallback counter."""
+    from repro.kernels import use_pallas
+    from repro.kernels.flash_attention import ops as fa_ops
+    from repro.kernels.rglru import ops as lru_ops
+    from repro.kernels.rwkv6 import ops as wkv_ops
+    from repro.obs import metrics
+
+    metrics.enable()
+    if kernel == "flash_attention":
+        q = _randn((1, 192, 2, 32), jnp.float32)  # 192 is no multiple of 128
+        call = lambda: fa_ops.attention(q, q, q)
+        want = attention_ref(*(a.transpose(0, 2, 1, 3) for a in (q, q, q))).transpose(0, 2, 1, 3)
+    elif kernel == "rglru":
+        a = jnp.asarray(RNG.uniform(0.5, 0.99, (1, 192, 128)), jnp.float32)
+        call = lambda: lru_ops.scan(a, a)
+        want = rglru_ref(a, a)[0]
+    else:
+        r = _randn((1, 40, 1, 16), jnp.float32)  # 40 is no multiple of 32
+        call = lambda: wkv_ops.wkv(r, r, r, -jnp.abs(r), jnp.zeros((1, 16)))[0]
+        want = wkv6_ref(r, r, r, -jnp.abs(r), jnp.zeros((1, 16)))[0]
+    with use_pallas(True), caplog.at_level("WARNING", logger="repro.kernels.config"):
+        got = call()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    assert metrics.registry().counters[f"kernels.fallback.{kernel}"].value == 1
+    assert any(kernel in r.getMessage() for r in caplog.records)

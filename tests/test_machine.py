@@ -351,3 +351,24 @@ def test_direct_only_fit():
     costs = plan_costs(spec, 4096.0, 4)
     assert list(costs) == ["gpudirect"] and costs["gpudirect"] > 0
     assert "fitted_direct_only" not in registered_machines()
+
+
+@pytest.mark.parametrize("bench", ["h2d", "d2h", "direct", "staged"])
+def test_transfer_benchmarks_fit_postal(bench):
+    """Each transfer benchmark yields one positive sample per size and a
+    postal fit; on one device the ping-pongs go device 0 <-> device 0."""
+    import jax
+
+    from repro.core import benchmark as bm
+
+    dev = jax.devices()[0]
+    sizes = (1 << 10, 1 << 14)
+    res = {
+        "h2d": lambda: bm.bench_host_device_roundtrip(sizes),
+        "d2h": lambda: bm.bench_device_to_host(sizes, samples=3),
+        "direct": lambda: bm.bench_device_pingpong(dev, dev, sizes),
+        "staged": lambda: bm.bench_staged_pingpong(dev, dev, sizes),
+    }[bench]()
+    assert list(res.sizes) == list(sizes) and len(res.times) == 2
+    assert all(t > 0 for t in res.times)
+    assert res.fitted.alpha >= 0 and np.isfinite(res.fitted.beta)
