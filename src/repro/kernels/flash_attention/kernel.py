@@ -149,6 +149,7 @@ def flash_attention(
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",  # the HLO custom call's name, and the profiler's
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),

@@ -72,6 +72,7 @@ def rglru_scan(
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
+        name="rglru",  # the HLO custom call's name, and the profiler's
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),

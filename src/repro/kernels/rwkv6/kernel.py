@@ -126,6 +126,7 @@ def wkv6(
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         interpret=interpret,
+        name="wkv6",  # the HLO custom call's name, and the profiler's
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),

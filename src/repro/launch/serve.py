@@ -6,10 +6,12 @@ iterated with the KV/recurrent caches donated in place.
 
 Observability (DESIGN.md §8): the run enables :mod:`repro.obs.metrics`
 and, with ``--trace``, a :mod:`repro.obs.trace` tracer — so one serve run
-emits one Perfetto-loadable timeline (prefill / per-token decode / plan
-spans on the wall clock, plus the simulated per-resource timeline of the
-collective the planner picked) and a one-line metrics digest at exit in
-place of the old ad-hoc cache print.
+emits one Perfetto-loadable timeline (``serve.prefill``, and per token
+``serve.readback`` / ``serve.plan`` / ``serve.decode_step`` spans on the
+wall clock, plus the simulated per-resource timeline of the collective the
+planner picked) and a one-line metrics digest at exit in place of the old
+ad-hoc cache print.  Under a ``jax.profiler`` trace the same spans land on
+the profile's host plane, beside the device's work.
 """
 from __future__ import annotations
 
@@ -147,7 +149,7 @@ def _serve(args, mesh) -> ServeResult:
         ).lower(params, prompts, frontend=frontend).compile()
     t_compile = time.perf_counter() - t0
     t0 = time.perf_counter()
-    with trace.span("prefill", batch=B, prompt_len=P_len):
+    with trace.span("serve.prefill", batch=B, prompt_len=P_len):
         logits, caches = prefill_fn(params, prompts, frontend=frontend)
         logits.block_until_ready()
     t_prefill = time.perf_counter() - t0
@@ -265,31 +267,32 @@ def _serve(args, mesh) -> ServeResult:
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
     t0 = time.perf_counter()
     for i in range(N):
-        with trace.span("decode.step", token=i):
+        with trace.span("serve.readback", token=i):
             out_tokens.append(np.asarray(tok)[:, 0])
-            if degrade_spec is not None:
-                tier = degrade_spec.tiers[args.degrade_tier]
-                t_model = float(tier.time(degrade_probe_bytes))
-                sag = args.degrade_factor if i >= args.degrade_at else 1.0
-                drift.record(degrade_machine, args.degrade_tier, "probe",
-                             degrade_probe_bytes, t_model, sag * t_model)
-                lk = health.monitor().link(degrade_machine, args.degrade_tier)
-                if lk.state == health.DEGRADED and not degrade_refit_done:
-                    degrade_refit_done = True
-                    fit, _ = health.refit_degraded(
-                        degrade_spec, lk, register_as=degrade_machine
-                    )
-                    print(f"[serve] link {lk.key} degraded at decode step {i} "
-                          f"(detected in {lk.detection_records} records); "
-                          f"refit beta x{fit.beta_scale:.1f}, replanning")
-            if scenario_injector is not None:
-                scenario_injector.feed_drift(i)
-            for host in drop_at.pop(i, ()):
-                handle_host_drop(i, host)
-            with trace.span("plan"):
-                collective = select_allreduce_strategy(
-                    plan_shape, token_bytes * (P_len + i + 1)
+        if degrade_spec is not None:
+            tier = degrade_spec.tiers[args.degrade_tier]
+            t_model = float(tier.time(degrade_probe_bytes))
+            sag = args.degrade_factor if i >= args.degrade_at else 1.0
+            drift.record(degrade_machine, args.degrade_tier, "probe",
+                         degrade_probe_bytes, t_model, sag * t_model)
+            lk = health.monitor().link(degrade_machine, args.degrade_tier)
+            if lk.state == health.DEGRADED and not degrade_refit_done:
+                degrade_refit_done = True
+                fit, _ = health.refit_degraded(
+                    degrade_spec, lk, register_as=degrade_machine
                 )
+                print(f"[serve] link {lk.key} degraded at decode step {i} "
+                      f"(detected in {lk.detection_records} records); "
+                      f"refit beta x{fit.beta_scale:.1f}, replanning")
+        if scenario_injector is not None:
+            scenario_injector.feed_drift(i)
+        for host in drop_at.pop(i, ()):
+            handle_host_drop(i, host)
+        with trace.span("serve.plan"):
+            collective = select_allreduce_strategy(
+                plan_shape, token_bytes * (P_len + i + 1)
+            )
+        with trace.span("serve.decode_step", token=i):
             logits, caches = decode_fn(params, caches, tok, jnp.int32(P_len + i))
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
         metrics.inc("serve.decode.tokens", int(tok.shape[0]))

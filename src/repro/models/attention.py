@@ -270,6 +270,7 @@ def cache_capacity(window: int, seq_len: int) -> int:
     return min(window, seq_len) if window else seq_len
 
 
+@jax.named_scope("kv_cache")
 def cache_from_kv(
     k: jax.Array,  # (B, S, G, dh) — rope already applied
     v: jax.Array,
@@ -315,15 +316,18 @@ def decode_attention(
         q = apply_rope(q, pos_b, cfg.rope_theta)
         k_new = apply_rope(k_new, pos_b, cfg.rope_theta)
 
-    capacity = cache["k"].shape[1]
-    slot = jnp.where(window > 0, pos % capacity, jnp.minimum(pos, capacity - 1))
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
-    kpos = jax.lax.dynamic_update_slice(cache["pos"], pos[None].astype(jnp.int32), (slot,))
-    new_cache = {"k": k, "v": v, "pos": kpos}
+    # the cache write and the mask read from the cache's positions; the
+    # score path below reads k and v under the enclosing scope
+    with jax.named_scope("kv_cache"):
+        capacity = cache["k"].shape[1]
+        slot = jnp.where(window > 0, pos % capacity, jnp.minimum(pos, capacity - 1))
+        k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
+        v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
+        kpos = jax.lax.dynamic_update_slice(cache["pos"], pos[None].astype(jnp.int32), (slot,))
+        new_cache = {"k": k, "v": v, "pos": kpos}
+        bias = _mask_bias(pos[None].astype(jnp.int32), kpos, window, causal=True)
 
     qg = _split_groups(cfg, q)  # (B, 1, G, M, dh)
-    bias = _mask_bias(pos[None].astype(jnp.int32), kpos, window, causal=True)
     out = _attend_dense(cfg, qg, k, v, bias)
     out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim_)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])

@@ -12,6 +12,14 @@ Cache contents by layer kind:
   ATTNX  — self KV cache + cross K/V (whisper decoder).
   RWKV   — WKV state (B,H,K,V) + token-shift states (O(1)).
   RGLRU  — recurrence state (B,W) + conv tail (O(1)).
+
+Both programs name their parts with ``jax.named_scope``, which the compiler
+keeps in each op's ``op_name`` and the device profiler shows: the root
+``prefill`` or ``decode``, then ``embed``, ``layers`` (the layer scan; its
+own stacking and carry of the caches is named by nothing deeper), one scope
+per sublayer (``attn`` with ``kv_cache`` nested, ``xattn``, ``mlp``/``moe``,
+``time_mix`` with ``wkv`` nested, ``channel_mix``, ``recurrent``), and
+``lm_head``.  Scopes are metadata: they add no op to the programs.
 """
 from __future__ import annotations
 
@@ -37,7 +45,7 @@ from repro.models.transformer import (
     _constrain,
     _dp_spec,
     _embed_tokens,
-    _moe_call,
+    _ffn,
     _positions_embed,
     _run_encoder,
 )
@@ -101,61 +109,66 @@ def _prefill_layer(
 ) -> Tuple[jax.Array, dict]:
     if kind in (ATTN, LOCAL):
         window = cfg.window if kind == LOCAL else 0
-        h = apply_norm(cfg, x, p["ln1"])
-        q, k, v = attn.qkv_proj(cfg, p["attn"], h, positions)
-        cap = capacity if kind == ATTN else attn.cache_capacity(cfg.window, capacity)
-        cache = attn.cache_from_kv(k, v, positions, cap)
-        o = attn.attend(cfg, q, k, v, positions, positions, window=window)
-        a = attn.out_proj(p["attn"], o)
-        if cfg.post_norms:
-            a = apply_norm(cfg, a, p["post_ln1"])
-        x = x + a
-        h = apply_norm(cfg, x, p["ln2"])
-        if cfg.is_moe:
-            m, _ = _moe_call(cfg, p["moe"], h, dist)
-        else:
-            m = mlp_apply(cfg, p["mlp"], h)
-        if cfg.post_norms:
-            m = apply_norm(cfg, m, p["post_ln2"])
-        return x + m, cache
+        with jax.named_scope("attn"):
+            h = apply_norm(cfg, x, p["ln1"])
+            q, k, v = attn.qkv_proj(cfg, p["attn"], h, positions)
+            cap = capacity if kind == ATTN else attn.cache_capacity(cfg.window, capacity)
+            cache = attn.cache_from_kv(k, v, positions, cap)
+            o = attn.attend(cfg, q, k, v, positions, positions, window=window)
+            a = attn.out_proj(p["attn"], o)
+            if cfg.post_norms:
+                a = apply_norm(cfg, a, p["post_ln1"])
+            x = x + a
+        x, _ = _ffn(cfg, p, x, dist)
+        return x, cache
     if kind == XATTN:
-        ck, cv = attn.cross_kv(cfg, p["xattn"], enc)
-        h = apply_norm(cfg, x, p["ln1"])
-        a = attn.cross_attention(cfg, p["xattn"], h, (ck, cv))
-        x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * a
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * mlp_apply(cfg, p["mlp"], h)
+        with jax.named_scope("xattn"):
+            ck, cv = attn.cross_kv(cfg, p["xattn"], enc)
+            h = apply_norm(cfg, x, p["ln1"])
+            a = attn.cross_attention(cfg, p["xattn"], h, (ck, cv))
+            x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * a
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * mlp_apply(cfg, p["mlp"], h)
         return x, {"ck": ck, "cv": cv}
     if kind == ATTNX:
-        h = apply_norm(cfg, x, p["ln1"])
-        q, k, v = attn.qkv_proj(cfg, p["attn"], h, positions)
-        kv = attn.cache_from_kv(k, v, positions, capacity)
-        o = attn.attend(cfg, q, k, v, positions, positions)
-        x = x + attn.out_proj(p["attn"], o)
-        ck, cv = attn.cross_kv(cfg, p["xattn"], enc)
-        h = apply_norm(cfg, x, p["ln_x"])
-        x = x + attn.cross_attention(cfg, p["xattn"], h, (ck, cv))
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + mlp_apply(cfg, p["mlp"], h)
+        with jax.named_scope("attn"):
+            h = apply_norm(cfg, x, p["ln1"])
+            q, k, v = attn.qkv_proj(cfg, p["attn"], h, positions)
+            kv = attn.cache_from_kv(k, v, positions, capacity)
+            o = attn.attend(cfg, q, k, v, positions, positions)
+            x = x + attn.out_proj(p["attn"], o)
+        with jax.named_scope("xattn"):
+            ck, cv = attn.cross_kv(cfg, p["xattn"], enc)
+            h = apply_norm(cfg, x, p["ln_x"])
+            x = x + attn.cross_attention(cfg, p["xattn"], h, (ck, cv))
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + mlp_apply(cfg, p["mlp"], h)
         return x, {"kv": kv, "ck": ck, "cv": cv}
     if kind == RWKV:
-        h = apply_norm(cfg, x, p["ln1"])
-        y, state = rwkv.rwkv_time_mix_prefill(cfg, p["tm_cm"], h)
-        x = x + y
-        h2 = apply_norm(cfg, x, p["ln2"])
-        x = x + rwkv.rwkv_channel_mix(cfg, p["tm_cm"], h2)
+        with jax.named_scope("time_mix"):
+            h = apply_norm(cfg, x, p["ln1"])
+            y, state = rwkv.rwkv_time_mix_prefill(cfg, p["tm_cm"], h)
+            x = x + y
+        with jax.named_scope("channel_mix"):
+            h2 = apply_norm(cfg, x, p["ln2"])
+            x = x + rwkv.rwkv_channel_mix(cfg, p["tm_cm"], h2)
         cache = {"state": state, "tm_shift": h[:, -1], "cm_shift": h2[:, -1]}
         return x, cache
     if kind == RGLRU:
-        h = apply_norm(cfg, x, p["ln1"])
-        y, cache = griffin.rglru_block_prefill(cfg, p["rec"], h)
-        x = x + y
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + mlp_apply(cfg, p["mlp"], h)
+        with jax.named_scope("recurrent"):
+            h = apply_norm(cfg, x, p["ln1"])
+            y, cache = griffin.rglru_block_prefill(cfg, p["rec"], h)
+            x = x + y
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + mlp_apply(cfg, p["mlp"], h)
         return x, cache
     raise ValueError(kind)
 
 
+@jax.named_scope("prefill")
 def prefill(
     cfg: ModelConfig,
     params: dict,
@@ -177,8 +190,9 @@ def prefill(
     elif cfg.family == "vlm":
         enc = frontend
 
-    x = _embed_tokens(cfg, params, tokens)
-    x = _positions_embed(cfg, params, x, positions)
+    with jax.named_scope("embed"):
+        x = _embed_tokens(cfg, params, tokens)
+        x = _positions_embed(cfg, params, x, positions)
     if dist:
         x = _constrain(x, dist, dp_spec)
 
@@ -194,11 +208,13 @@ def prefill(
                 x = _constrain(x, dist, dp_spec)
             return x, tuple(outs)
 
-        x, cache_stack = jax.lax.scan(block, x, gp)
+        with jax.named_scope("layers"):
+            x, cache_stack = jax.lax.scan(block, x, gp)
         caches.append(cache_stack)
 
-    x = apply_norm(cfg, x, params["final_norm"])
-    logits = unembed(cfg, params["embed"], x[:, -1])
+    with jax.named_scope("lm_head"):
+        x = apply_norm(cfg, x, params["final_norm"])
+        logits = unembed(cfg, params["embed"], x[:, -1])
     return logits, tuple(caches)
 
 
@@ -216,56 +232,60 @@ def _decode_layer(
     dist: Optional[DistContext],
 ) -> Tuple[jax.Array, dict]:
     if kind in (ATTN, LOCAL):
-        h = apply_norm(cfg, x, p["ln1"])
-        a, cache = attn.decode_attention(
-            cfg, p["attn"], h, pos, cache, window=cfg.window if kind == LOCAL else 0
-        )
-        if cfg.post_norms:
-            a = apply_norm(cfg, a, p["post_ln1"])
-        x = x + a
-        h = apply_norm(cfg, x, p["ln2"])
-        if cfg.is_moe:
-            m, _ = _moe_call(cfg, p["moe"], h, dist)
-        else:
-            m = mlp_apply(cfg, p["mlp"], h)
-        if cfg.post_norms:
-            m = apply_norm(cfg, m, p["post_ln2"])
-        x = x + m
+        with jax.named_scope("attn"):
+            h = apply_norm(cfg, x, p["ln1"])
+            a, cache = attn.decode_attention(
+                cfg, p["attn"], h, pos, cache, window=cfg.window if kind == LOCAL else 0
+            )
+            if cfg.post_norms:
+                a = apply_norm(cfg, a, p["post_ln1"])
+            x = x + a
+        x, _ = _ffn(cfg, p, x, dist)
         return x, cache
     if kind == XATTN:
-        h = apply_norm(cfg, x, p["ln1"])
-        a = attn.cross_attention(cfg, p["xattn"], h, (cache["ck"], cache["cv"]))
-        x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * a
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * mlp_apply(cfg, p["mlp"], h)
+        with jax.named_scope("xattn"):
+            h = apply_norm(cfg, x, p["ln1"])
+            a = attn.cross_attention(cfg, p["xattn"], h, (cache["ck"], cache["cv"]))
+            x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * a
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * mlp_apply(cfg, p["mlp"], h)
         return x, cache
     if kind == ATTNX:
-        h = apply_norm(cfg, x, p["ln1"])
-        a, kv = attn.decode_attention(cfg, p["attn"], h, pos, cache["kv"], window=0)
-        x = x + a
-        h = apply_norm(cfg, x, p["ln_x"])
-        x = x + attn.cross_attention(cfg, p["xattn"], h, (cache["ck"], cache["cv"]))
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + mlp_apply(cfg, p["mlp"], h)
+        with jax.named_scope("attn"):
+            h = apply_norm(cfg, x, p["ln1"])
+            a, kv = attn.decode_attention(cfg, p["attn"], h, pos, cache["kv"], window=0)
+            x = x + a
+        with jax.named_scope("xattn"):
+            h = apply_norm(cfg, x, p["ln_x"])
+            x = x + attn.cross_attention(cfg, p["xattn"], h, (cache["ck"], cache["cv"]))
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + mlp_apply(cfg, p["mlp"], h)
         return x, dict(cache, kv=kv)
     if kind == RWKV:
-        h = apply_norm(cfg, x, p["ln1"])
-        y, cache = rwkv.rwkv_time_mix_decode(cfg, p["tm_cm"], h, cache)
-        x = x + y
-        h2 = apply_norm(cfg, x, p["ln2"])
-        y2, cache = rwkv.rwkv_channel_mix_decode(cfg, p["tm_cm"], h2, cache)
-        x = x + y2
+        with jax.named_scope("time_mix"):
+            h = apply_norm(cfg, x, p["ln1"])
+            y, cache = rwkv.rwkv_time_mix_decode(cfg, p["tm_cm"], h, cache)
+            x = x + y
+        with jax.named_scope("channel_mix"):
+            h2 = apply_norm(cfg, x, p["ln2"])
+            y2, cache = rwkv.rwkv_channel_mix_decode(cfg, p["tm_cm"], h2, cache)
+            x = x + y2
         return x, cache
     if kind == RGLRU:
-        h = apply_norm(cfg, x, p["ln1"])
-        y, cache = griffin.rglru_block_decode(cfg, p["rec"], h, cache)
-        x = x + y
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + mlp_apply(cfg, p["mlp"], h)
+        with jax.named_scope("recurrent"):
+            h = apply_norm(cfg, x, p["ln1"])
+            y, cache = griffin.rglru_block_decode(cfg, p["rec"], h, cache)
+            x = x + y
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + mlp_apply(cfg, p["mlp"], h)
         return x, cache
     raise ValueError(kind)
 
 
+@jax.named_scope("decode")
 def decode_step(
     cfg: ModelConfig,
     params: dict,
@@ -277,8 +297,9 @@ def decode_step(
 ) -> Tuple[jax.Array, tuple]:
     """Returns (logits (B, V) f32, new_caches)."""
     dp_spec = _dp_spec(dist, token.shape[0])
-    x = _embed_tokens(cfg, params, token)
-    x = _positions_embed(cfg, params, x, pos[None])
+    with jax.named_scope("embed"):
+        x = _embed_tokens(cfg, params, token)
+        x = _positions_embed(cfg, params, x, pos[None])
     if dist:
         x = _constrain(x, dist, dp_spec)
 
@@ -293,9 +314,11 @@ def decode_step(
                 new_c.append(c2)
             return x, tuple(new_c)
 
-        x, cache_stack = jax.lax.scan(block, x, (gp, gc))
+        with jax.named_scope("layers"):
+            x, cache_stack = jax.lax.scan(block, x, (gp, gc))
         new_caches.append(cache_stack)
 
-    x = apply_norm(cfg, x, params["final_norm"])
-    logits = unembed(cfg, params["embed"], x[:, -1])
+    with jax.named_scope("lm_head"):
+        x = apply_norm(cfg, x, params["final_norm"])
+        logits = unembed(cfg, params["embed"], x[:, -1])
     return logits, tuple(new_caches)

@@ -87,6 +87,7 @@ def wkv_recurrent(
     return ys.transpose(1, 0, 2, 3).astype(r.dtype), s_fin
 
 
+@jax.named_scope("wkv")
 def wkv_decode_step(
     r: jax.Array, k: jax.Array, v: jax.Array, log_w: jax.Array, u: jax.Array,
     state: jax.Array,
@@ -192,9 +193,11 @@ def _heads(cfg: ModelConfig, a: jax.Array) -> jax.Array:
     return a.reshape(B, S, d // K, K)
 
 
+@jax.named_scope("wkv")
 def _wkv_dispatch(rh, kh, vh, lwh, u, chunked: bool, chunk: int = WKV_CHUNK):
     """Pallas kernel inside ``repro.kernels.use_pallas(True)``, else the
-    pure-XLA chunked scan (the dry-run path) or the recurrence oracle."""
+    pure-XLA chunked scan (the dry-run path) or the recurrence oracle.
+    Scoped ``wkv``, so the kernel's layout changes count with it."""
     from repro.kernels import config as kernels
     from repro.kernels.rwkv6 import ops as wkv_ops
 

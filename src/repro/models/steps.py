@@ -7,7 +7,11 @@
 ``decode_step`` — one token against the caches (see models/decode.py).
 
 All are pure functions of (params, state, batch) suitable for
-``jax.jit(..., in_shardings=..., out_shardings=...)``.
+``jax.jit(..., in_shardings=..., out_shardings=...)``.  ``train_step`` names
+its work for the device profiler (``jax.named_scope``): the root
+``train_step``, ``loss`` around the forward and backward passes (the
+backward's ops read ``transpose(jvp(loss))``), and ``optimizer`` around
+clipping and the AdamW update; the model's own scopes nest inside ``loss``.
 """
 from __future__ import annotations
 
@@ -44,6 +48,7 @@ def next_token_loss(
     return loss, {"loss": loss, "ce": ce, "aux": aux}
 
 
+@jax.named_scope("train_step")
 def train_step(
     cfg: ModelConfig,
     run: RunConfig,
@@ -62,6 +67,9 @@ def train_step(
 
     remat_mode = run.remat_policy if run.remat else "none"
 
+    # under value_and_grad the backward pass carries the scope too, as
+    # transpose(jvp(loss))
+    @jax.named_scope("loss")
     def loss_fn(p, toks, fr):
         return next_token_loss(
             cfg, p, toks, frontend=fr, dist=dist, remat=remat_mode
@@ -98,24 +106,25 @@ def train_step(
             params, tokens, frontend
         )
 
-    grads, gnorm = adamw.clip_by_global_norm(grads, run.grad_clip)
-    lr = warmup_cosine(
-        opt_state.step,
-        peak_lr=run.learning_rate,
-        warmup_steps=run.warmup_steps,
-        total_steps=run.total_steps,
-    )
-    new_params, new_state = adamw.apply_updates(
-        adamw.AdamWConfig(
-            lr=run.learning_rate,
-            weight_decay=run.weight_decay,
-            grad_clip=run.grad_clip,
-        ),
-        params,
-        grads,
-        opt_state,
-        lr=lr,
-    )
+    with jax.named_scope("optimizer"):
+        grads, gnorm = adamw.clip_by_global_norm(grads, run.grad_clip)
+        lr = warmup_cosine(
+            opt_state.step,
+            peak_lr=run.learning_rate,
+            warmup_steps=run.warmup_steps,
+            total_steps=run.total_steps,
+        )
+        new_params, new_state = adamw.apply_updates(
+            adamw.AdamWConfig(
+                lr=run.learning_rate,
+                weight_decay=run.weight_decay,
+                grad_clip=run.grad_clip,
+            ),
+            params,
+            grads,
+            opt_state,
+            lr=lr,
+        )
     metrics = dict(metrics, grad_norm=gnorm, lr=lr)
     return new_params, new_state, metrics
 

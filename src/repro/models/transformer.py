@@ -234,13 +234,61 @@ def _apply_layer_full(
     """Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in (ATTN, LOCAL):
-        h = apply_norm(cfg, x, p["ln1"])
-        a = attn.self_attention(
-            cfg, p["attn"], h, positions, window=cfg.window if kind == LOCAL else 0
-        )
-        if cfg.post_norms:
-            a = apply_norm(cfg, a, p["post_ln1"])
-        x = x + a
+        with jax.named_scope("attn"):
+            h = apply_norm(cfg, x, p["ln1"])
+            a = attn.self_attention(
+                cfg, p["attn"], h, positions, window=cfg.window if kind == LOCAL else 0
+            )
+            if cfg.post_norms:
+                a = apply_norm(cfg, a, p["post_ln1"])
+            x = x + a
+        x, aux = _ffn(cfg, p, x, dist)
+    elif kind == XATTN:
+        with jax.named_scope("xattn"):
+            h = apply_norm(cfg, x, p["ln1"])
+            kv = attn.cross_kv(cfg, p["xattn"], enc)
+            a = attn.cross_attention(cfg, p["xattn"], h, kv)
+            x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * a
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * mlp_apply(cfg, p["mlp"], h)
+    elif kind == ATTNX:
+        with jax.named_scope("attn"):
+            h = apply_norm(cfg, x, p["ln1"])
+            x = x + attn.self_attention(cfg, p["attn"], h, positions, window=0)
+        with jax.named_scope("xattn"):
+            h = apply_norm(cfg, x, p["ln_x"])
+            kv = attn.cross_kv(cfg, p["xattn"], enc)
+            x = x + attn.cross_attention(cfg, p["xattn"], h, kv)
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + mlp_apply(cfg, p["mlp"], h)
+    elif kind == RWKV:
+        with jax.named_scope("time_mix"):
+            h = apply_norm(cfg, x, p["ln1"])
+            x = x + rwkv.rwkv_time_mix(cfg, p["tm_cm"], h)
+        with jax.named_scope("channel_mix"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + rwkv.rwkv_channel_mix(cfg, p["tm_cm"], h)
+    elif kind == RGLRU:
+        with jax.named_scope("recurrent"):
+            h = apply_norm(cfg, x, p["ln1"])
+            x = x + griffin.rglru_block(cfg, p["rec"], h)
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg, x, p["ln2"])
+            x = x + mlp_apply(cfg, p["mlp"], h)
+    else:
+        raise ValueError(kind)
+    return x, aux
+
+
+def _ffn(
+    cfg: ModelConfig, p: dict, x: jax.Array, dist: Optional[DistContext]
+) -> Tuple[jax.Array, jax.Array]:
+    """Feed-forward sublayer of an attention layer (norm, MLP or MoE,
+    residual), scoped ``mlp`` or ``moe``.  Returns (x, aux_loss)."""
+    aux = jnp.zeros((), jnp.float32)
+    with jax.named_scope("moe" if cfg.is_moe else "mlp"):
         h = apply_norm(cfg, x, p["ln2"])
         if cfg.is_moe:
             m, aux = _moe_call(cfg, p["moe"], h, dist)
@@ -248,35 +296,7 @@ def _apply_layer_full(
             m = mlp_apply(cfg, p["mlp"], h)
         if cfg.post_norms:
             m = apply_norm(cfg, m, p["post_ln2"])
-        x = x + m
-    elif kind == XATTN:
-        h = apply_norm(cfg, x, p["ln1"])
-        kv = attn.cross_kv(cfg, p["xattn"], enc)
-        a = attn.cross_attention(cfg, p["xattn"], h, kv)
-        x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * a
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * mlp_apply(cfg, p["mlp"], h)
-    elif kind == ATTNX:
-        h = apply_norm(cfg, x, p["ln1"])
-        x = x + attn.self_attention(cfg, p["attn"], h, positions, window=0)
-        h = apply_norm(cfg, x, p["ln_x"])
-        kv = attn.cross_kv(cfg, p["xattn"], enc)
-        x = x + attn.cross_attention(cfg, p["xattn"], h, kv)
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + mlp_apply(cfg, p["mlp"], h)
-    elif kind == RWKV:
-        h = apply_norm(cfg, x, p["ln1"])
-        x = x + rwkv.rwkv_time_mix(cfg, p["tm_cm"], h)
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + rwkv.rwkv_channel_mix(cfg, p["tm_cm"], h)
-    elif kind == RGLRU:
-        h = apply_norm(cfg, x, p["ln1"])
-        x = x + griffin.rglru_block(cfg, p["rec"], h)
-        h = apply_norm(cfg, x, p["ln2"])
-        x = x + mlp_apply(cfg, p["mlp"], h)
-    else:
-        raise ValueError(kind)
-    return x, aux
+        return x + m, aux
 
 
 # --------------------------------------------------------------------------
@@ -337,8 +357,9 @@ def forward(
     elif cfg.family == "vlm":
         enc = frontend  # raw patch embeddings; XATTN projects K/V from them
 
-    x = _embed_tokens(cfg, params, tokens)
-    x = _positions_embed(cfg, params, x, positions)
+    with jax.named_scope("embed"):
+        x = _embed_tokens(cfg, params, tokens)
+        x = _positions_embed(cfg, params, x, positions)
     x = _constrain(x, dist, dp_spec) if dist else x
 
     aux_total = jnp.zeros((), jnp.float32)
@@ -362,8 +383,10 @@ def forward(
             )
         else:
             body = block
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), gp)
+        with jax.named_scope("layers"):
+            (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), gp)
 
-    x = apply_norm(cfg, x, params["final_norm"])
-    logits = unembed(cfg, params["embed"], x)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(cfg, x, params["final_norm"])
+        logits = unembed(cfg, params["embed"], x)
     return logits, aux_total * AUX_LOSS_COEF

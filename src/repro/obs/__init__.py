@@ -3,8 +3,12 @@
 Three stdlib-only pillars (see DESIGN.md §8):
 
 * :mod:`repro.obs.trace` — Chrome ``trace_event`` export: wall-clock spans
-  (plan / lower / simulate / decode.step) plus per-resource-lane timelines
-  of every ``run_schedule`` result, one Perfetto-loadable file per run.
+  (plan / lower / simulate, and the serve loop's serve.prefill /
+  serve.readback / serve.plan / serve.decode_step) plus per-resource-lane
+  timelines of every ``run_schedule`` result, one Perfetto-loadable file
+  per run.  While a tracer is active the spans are also
+  ``jax.profiler.TraceAnnotation``s, so a ``jax.profiler`` trace holds them
+  on its host plane, on the device trace's clock.
 * :mod:`repro.obs.metrics` — process-global counters / gauges / histograms
   with a zero-cost disabled mode (cache hit rates, engine heap ops,
   planner latency, schedule-pick distributions).

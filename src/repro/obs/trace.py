@@ -3,9 +3,14 @@
 Two timebases share one trace file, separated by pid:
 
 * **pid 0 — wall clock.**  :func:`span` events (``plan`` / ``lower`` /
-  ``simulate`` / ``decode.step``), timestamped with ``perf_counter``
-  relative to tracer start.  This is the serve path's plan->lower->
-  simulate->step storyline.
+  ``simulate``, and the serve loop's ``serve.prefill``, ``serve.readback``,
+  ``serve.plan`` and ``serve.decode_step``), timestamped with
+  ``perf_counter`` relative to tracer start.  The tracer's start is also
+  written on the profiler's clock (``time.time_ns()``) as the metadata key
+  ``t0_unix_ns``, and while a tracer is active each span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name: under a ``jax.profiler``
+  trace the same spans land on the profile's host plane, on the clock of
+  the device trace, so an idle gap on the chip can be put on one of them.
 * **pid 1, 2, ... — simulated time.**  Each recorded
   :class:`~repro.core.events.SimResult` becomes its own process: one
   thread (tid) per *lane* of each :class:`Resource` (a capacity-3 NIC is
@@ -20,8 +25,8 @@ scaled by 1e6.  The export is a plain dict (``{"traceEvents": [...],
 "metadata": {...}}``) so it round-trips through ``json`` and loads in
 Perfetto / ``chrome://tracing`` unchanged.
 
-This module deliberately imports nothing from ``repro.core`` at module
-scope: ``repro.core.events`` feeds results in through the sink
+This module deliberately imports nothing from ``repro.core`` (nor JAX) at
+module scope: ``repro.core.events`` feeds results in through the sink
 :mod:`repro.obs` installs, and everything here duck-types the SimResult /
 StepTrace fields, so there is no import cycle.
 """
@@ -53,8 +58,8 @@ class Tracer:
         self.name = name
         self.record_schedules = record_schedules
         self.events: List[dict] = []
-        self.metadata: Dict[str, Any] = {"trace_name": name}
         self.t0 = time.perf_counter()
+        self.metadata: Dict[str, Any] = {"trace_name": name, "t0_unix_ns": time.time_ns()}
         self._next_pid = WALL_PID + 1
         self._next_flow_id = 1
         self._span_depth = 0
@@ -182,7 +187,8 @@ def is_active() -> bool:
 
 @contextmanager
 def span(name: str, **args) -> Iterator[None]:
-    """Wall-clock span on the active tracer; no-op when tracing is off.
+    """Wall-clock span on the active tracer, and a ``TraceAnnotation`` of
+    the same name for a ``jax.profiler`` trace; no-op when tracing is off.
 
     The disabled path is one module-global check — cheap enough to leave in
     planner entry points permanently (measured in ``tracing_overhead``).
@@ -191,9 +197,12 @@ def span(name: str, **args) -> Iterator[None]:
     if t is None:
         yield
         return
+    from jax.profiler import TraceAnnotation
+
     t_begin = t.begin_span(name, **args)
     try:
-        yield
+        with TraceAnnotation(name):
+            yield
     finally:
         t.end_span(name, t_begin, **args)
 
