@@ -297,16 +297,30 @@ def cache_from_kv(
     }
 
 
+def _cache_slot(pos: jax.Array, capacity: int, window: int) -> jax.Array:
+    """Slot of absolute position ``pos`` in a cache of ``capacity``: the
+    ring-buffer slot for a sliding window, else the position itself."""
+    return jnp.where(window > 0, pos % capacity, jnp.minimum(pos, capacity - 1))
+
+
 def decode_attention(
     cfg: ModelConfig,
     p: dict,
     x: jax.Array,  # (B, 1, d)
     pos: jax.Array,  # scalar int32 — absolute position of the new token
-    cache: dict,
+    cache: dict,  # k, v (L, B, capacity, G, dh); pos (L, capacity)
+    layer: jax.Array,  # scalar int32 — which of the L layers
     *,
     window: int = 0,
-) -> Tuple[jax.Array, dict]:
-    """One-token self-attention against (and updating) the KV cache."""
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """One-token self-attention against layer ``layer`` of a KV cache stacked
+    over layers, which it reads and does not write.  The scores take the
+    cache with the slot the new token will overwrite masked, and the new
+    token's own k/v beside it, in one softmax.  Returns the output and the
+    new token's (k, v), (B, 1, G, dh) each, for ``write_kv``.  Left
+    unwritten inside the layer scan, the stack keeps its layout and its
+    reads fuse into the scores; a write there made the TPU compiler copy
+    the whole stack, or a layer's slice, every step."""
     B = x.shape[0]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k_new = jnp.einsum("bsd,dgk->bsgk", x, p["wk"])
@@ -316,19 +330,50 @@ def decode_attention(
         q = apply_rope(q, pos_b, cfg.rope_theta)
         k_new = apply_rope(k_new, pos_b, cfg.rope_theta)
 
-    # the cache write and the mask read from the cache's positions; the
-    # score path below reads k and v under the enclosing scope
+    # the mask read from the cache's positions; the score path below reads
+    # k and v under the enclosing scope
     with jax.named_scope("kv_cache"):
-        capacity = cache["k"].shape[1]
-        slot = jnp.where(window > 0, pos % capacity, jnp.minimum(pos, capacity - 1))
-        k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
-        kpos = jax.lax.dynamic_update_slice(cache["pos"], pos[None].astype(jnp.int32), (slot,))
-        new_cache = {"k": k, "v": v, "pos": kpos}
-        bias = _mask_bias(pos[None].astype(jnp.int32), kpos, window, causal=True)
+        capacity = cache["k"].shape[2]
+        pos = pos.astype(jnp.int32)
+        kpos = jax.lax.dynamic_index_in_dim(cache["pos"], layer, keepdims=False)
+        kpos = jnp.where(jnp.arange(capacity) == _cache_slot(pos, capacity, window), -1, kpos)
+        bias = _mask_bias(pos[None], kpos, window, causal=True)[0]  # (capacity,)
 
-    qg = _split_groups(cfg, q)  # (B, 1, G, M, dh)
-    out = _attend_dense(cfg, qg, k, v, bias)
-    out = out.reshape(B, 1, cfg.n_heads, cfg.head_dim_)
+    k = jax.lax.dynamic_index_in_dim(cache["k"], layer, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(cache["v"], layer, keepdims=False)
+    qg = _split_groups(cfg, q)[:, 0].astype(jnp.float32) * _scale(cfg)  # (B, G, M, dh)
+    logits = jnp.einsum("btgd,bgmd->btgm", k.astype(jnp.float32), qg)
+    logits = softcap(logits, cfg.attn_softcap) + bias[None, :, None, None]
+    own = softcap(jnp.einsum("bgmd,bgd->bgm", qg, k_new[:, 0].astype(jnp.float32)),
+                  cfg.attn_softcap)
+    top = jnp.maximum(logits.max(axis=1), own)
+    probs = jnp.exp(logits - top[:, None])
+    own = jnp.exp(own - top)
+    total = probs.sum(axis=1) + own
+    out = jnp.einsum("btgm,btgd->bgmd", (probs / total[:, None]).astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    out = out + (own / total)[..., None] * v_new[:, 0, :, None].astype(jnp.float32)
+    out = out.astype(v.dtype).reshape(B, 1, cfg.n_heads, cfg.head_dim_)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return y, new_cache
+    return y, (k_new, v_new)
+
+
+@jax.named_scope("kv_cache")
+def write_kv(
+    cache: dict,  # k, v (L, B, capacity, G, dh); pos (L, capacity)
+    k_new: jax.Array,  # (L, B, 1, G, dh): every layer's new token
+    v_new: jax.Array,
+    pos: jax.Array,  # scalar int32
+    *,
+    window: int = 0,
+) -> dict:
+    """Write every layer's new token into its slot, in place."""
+    L, capacity = cache["pos"].shape
+    pos = pos.astype(jnp.int32)
+    slot = _cache_slot(pos, capacity, window)
+    at = (0, 0, slot, 0, 0)
+    return {
+        "k": jax.lax.dynamic_update_slice(cache["k"], k_new, at),
+        "v": jax.lax.dynamic_update_slice(cache["v"], v_new, at),
+        "pos": jax.lax.dynamic_update_slice(cache["pos"], jnp.full((L, 1), pos), (0, slot)),
+    }

@@ -1,8 +1,14 @@
 """Serving entry points: cache init, prefill, and single-token decode.
 
 Caches mirror the parameter structure — one pytree per layer group with
-leaves stacked over the group's ``count`` so the decode step scans layers
-with ``lax.scan(body, x, (param_stack, cache_stack))``.
+leaves stacked over the group's ``count``.  The decode step scans the
+layers with ``lax.scan(body, (x, cache_stack), (layer_index, param_stack))``:
+the stacked caches ride in the carry and are never copied whole.  Each
+layer reads its slice of the stack, and what the step changes is written
+in place, by layer kind: a KV cache is only read inside the scan, and each
+layer's new k/v (one token) is written into the donated stack after it; a
+recurrent state (RWKV, RG-LRU) is rewritten in its layer's slice of the
+carry; cross K/V is never written.
 
 Cache contents by layer kind:
   ATTN   — global KV cache, capacity = max sequence length.
@@ -222,48 +228,66 @@ def prefill(
 # Decode: one token against the caches.
 # --------------------------------------------------------------------------
 
+def _layer_of(stack, i: jax.Array):
+    """Layer ``i`` of a cache stacked over its group's count."""
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), stack)
+
+
+def _with_layer(stack, i: jax.Array, layer):
+    """``stack`` with layer ``i`` replaced by ``layer``, in place."""
+    return jax.tree.map(lambda a, l: jax.lax.dynamic_update_index_in_dim(a, l, i, 0), stack, layer)
+
+
 def _decode_layer(
     cfg: ModelConfig,
     kind: str,
     p: dict,
     x: jax.Array,  # (B, 1, d)
     pos: jax.Array,  # scalar
-    cache: dict,
+    stack: dict,  # this kind's cache, stacked over the group's count
+    i: jax.Array,  # scalar: the layer's index in the stack
     dist: Optional[DistContext],
-) -> Tuple[jax.Array, dict]:
+) -> Tuple[jax.Array, dict, Optional[tuple]]:
+    """Returns (x, the stack, the new token's (k, v) or None).  A recurrent
+    state comes back with layer ``i`` rewritten in place; a KV cache and
+    cross K/V are only read here, and the new token's k/v are written after
+    the layer scan (``_write_tokens``)."""
     if kind in (ATTN, LOCAL):
         with jax.named_scope("attn"):
             h = apply_norm(cfg, x, p["ln1"])
-            a, cache = attn.decode_attention(
-                cfg, p["attn"], h, pos, cache, window=cfg.window if kind == LOCAL else 0
+            a, kv = attn.decode_attention(
+                cfg, p["attn"], h, pos, stack, i, window=cfg.window if kind == LOCAL else 0
             )
             if cfg.post_norms:
                 a = apply_norm(cfg, a, p["post_ln1"])
             x = x + a
         x, _ = _ffn(cfg, p, x, dist)
-        return x, cache
+        return x, stack, kv
     if kind == XATTN:
+        cross = _layer_of(stack, i)
         with jax.named_scope("xattn"):
             h = apply_norm(cfg, x, p["ln1"])
-            a = attn.cross_attention(cfg, p["xattn"], h, (cache["ck"], cache["cv"]))
+            a = attn.cross_attention(cfg, p["xattn"], h, (cross["ck"], cross["cv"]))
             x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * a
         with jax.named_scope("mlp"):
             h = apply_norm(cfg, x, p["ln2"])
             x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) * mlp_apply(cfg, p["mlp"], h)
-        return x, cache
+        return x, stack, None
     if kind == ATTNX:
         with jax.named_scope("attn"):
             h = apply_norm(cfg, x, p["ln1"])
-            a, kv = attn.decode_attention(cfg, p["attn"], h, pos, cache["kv"], window=0)
+            a, kv = attn.decode_attention(cfg, p["attn"], h, pos, stack["kv"], i, window=0)
             x = x + a
+        cross = _layer_of({"ck": stack["ck"], "cv": stack["cv"]}, i)
         with jax.named_scope("xattn"):
             h = apply_norm(cfg, x, p["ln_x"])
-            x = x + attn.cross_attention(cfg, p["xattn"], h, (cache["ck"], cache["cv"]))
+            x = x + attn.cross_attention(cfg, p["xattn"], h, (cross["ck"], cross["cv"]))
         with jax.named_scope("mlp"):
             h = apply_norm(cfg, x, p["ln2"])
             x = x + mlp_apply(cfg, p["mlp"], h)
-        return x, dict(cache, kv=kv)
+        return x, stack, kv
     if kind == RWKV:
+        cache = _layer_of(stack, i)
         with jax.named_scope("time_mix"):
             h = apply_norm(cfg, x, p["ln1"])
             y, cache = rwkv.rwkv_time_mix_decode(cfg, p["tm_cm"], h, cache)
@@ -272,8 +296,9 @@ def _decode_layer(
             h2 = apply_norm(cfg, x, p["ln2"])
             y2, cache = rwkv.rwkv_channel_mix_decode(cfg, p["tm_cm"], h2, cache)
             x = x + y2
-        return x, cache
+        return x, _with_layer(stack, i, cache), None
     if kind == RGLRU:
+        cache = _layer_of(stack, i)
         with jax.named_scope("recurrent"):
             h = apply_norm(cfg, x, p["ln1"])
             y, cache = griffin.rglru_block_decode(cfg, p["rec"], h, cache)
@@ -281,8 +306,19 @@ def _decode_layer(
         with jax.named_scope("mlp"):
             h = apply_norm(cfg, x, p["ln2"])
             x = x + mlp_apply(cfg, p["mlp"], h)
-        return x, cache
+        return x, _with_layer(stack, i, cache), None
     raise ValueError(kind)
+
+
+def _write_tokens(cfg: ModelConfig, kind: str, stack: dict, kv, pos: jax.Array) -> dict:
+    """Every layer's new k/v, (count, B, 1, G, dh) each, into the stack."""
+    if kind in (ATTN, LOCAL):
+        with jax.named_scope("attn"):
+            return attn.write_kv(stack, *kv, pos, window=cfg.window if kind == LOCAL else 0)
+    if kind == ATTNX:
+        with jax.named_scope("attn"):
+            return dict(stack, kv=attn.write_kv(stack["kv"], *kv, pos))
+    return stack
 
 
 @jax.named_scope("decode")
@@ -306,17 +342,21 @@ def decode_step(
     new_caches = []
     for group, gp, gc in zip(cfg.groups, params["groups"], caches):
 
-        def block(x, inputs, _group=group):
-            p_block, c_block = inputs
-            new_c = []
-            for kind, p, c in zip(_group.pattern, p_block, c_block):
-                x, c2 = _decode_layer(cfg, kind, p, x, pos, c, dist)
-                new_c.append(c2)
-            return x, tuple(new_c)
+        def block(carry, inputs, _group=group):
+            x, c_block = carry
+            i, p_block = inputs
+            c_block, tokens = list(c_block), []
+            for j, (kind, p) in enumerate(zip(_group.pattern, p_block)):
+                x, c_block[j], kv = _decode_layer(cfg, kind, p, x, pos, c_block[j], i, dist)
+                tokens.append(kv)
+            return (x, tuple(c_block)), tuple(tokens)
 
         with jax.named_scope("layers"):
-            x, cache_stack = jax.lax.scan(block, x, (gp, gc))
-        new_caches.append(cache_stack)
+            (x, gc), tokens = jax.lax.scan(
+                block, (x, gc), (jnp.arange(group.count, dtype=jnp.int32), gp))
+            gc = tuple(_write_tokens(cfg, kind, c, kv, pos)
+                       for kind, c, kv in zip(group.pattern, gc, tokens))
+        new_caches.append(gc)
 
     with jax.named_scope("lm_head"):
         x = apply_norm(cfg, x, params["final_norm"])

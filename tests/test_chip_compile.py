@@ -113,3 +113,91 @@ def test_llama_prefill_compiles_with_flash_kernel(one_chip, monkeypatch):
         c = jax.jit(functools.partial(dec.prefill, cfg, capacity=P + N)).lower(
             params, tokens).compile()
     assert _has_kernel(c, "flash_attention")
+
+
+def _chip_harness():
+    """The on-chip benchmark's ``harness`` module (cells and their files)."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+
+    if "chip_harness" not in sys.modules:
+        path = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" / "harness.py"
+        spec = importlib.util.spec_from_file_location("chip_harness", path)
+        sys.modules["chip_harness"] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules["chip_harness"])
+    return sys.modules["chip_harness"]
+
+
+VIEWS = ("parameter", "get-tuple-element", "bitcast")
+
+
+def _computations(hlo: str):
+    """Instruction lines of each computation of a compiled module's text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            name = head[1]
+            comps[name] = []
+        elif name and re.match(r"\s*(?:ROOT )?%", line):
+            comps[name].append(line)
+    return comps
+
+
+def _buffers_of_shape(hlo: str, shape: str):
+    """For each buffer of array type ``shape`` (e.g. ``bf16[16,48,1024,16,128]``)
+    that an instruction outside a fusion makes: the opcodes that make values
+    of that shape in it, a fusion's taken from its fused computation.  A
+    parameter, tuple element or bitcast makes no buffer of its own."""
+    comps = _computations(hlo)
+    fused = {c for lines in comps.values() for line in lines
+             for c in re.findall(r"kind=k\w+, calls=%([\w.-]+)", line)}
+    made = re.compile(r"\s*(?:ROOT )?%\S+ = " + re.escape(shape) + r"\{[^}]*\} ([\w-]+)\(")
+
+    def makers(lines):
+        for line in lines:
+            m = made.match(line)
+            if not m or m[1] in VIEWS:
+                continue
+            if m[1] == "fusion":
+                yield from makers(comps[re.search(r"calls=%([\w.-]+)", line)[1]])
+            else:
+                yield m[1]
+
+    return [(ops, line.strip()[:160]) for name, lines in comps.items() if name not in fused
+            for line in lines for ops in [tuple(makers([line]))] if ops]
+
+
+def test_olmo_decode_step_writes_its_cache_in_place(one_chip):
+    """The chat cell's donated decode step (OLMo-1B at the configuration
+    file's widths, B=48, KV capacity 1024), as the chip compiles it: only
+    an in-place dynamic-update-slice makes a buffer of the stacked cache's
+    shape, no layer's slice of it is copied out for the attention, and the
+    program's temporaries stay under 1 GiB (one copy of the stacked K is
+    3 GiB)."""
+    from repro.models import decode as dec
+    from repro.models import init_params
+
+    harness = _chip_harness()
+    cell = harness.cell("olmo-1b.serve.chat")
+    cfg = harness.program_config(cell.config)
+    B = cell.traffic["batch"]
+    capacity = cell.traffic["prompt_len"] + cell.traffic["new_tokens"]
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(functools.partial(init_params, cfg), jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(functools.partial(dec.init_caches, cfg, B, capacity)))
+    step = jax.jit(functools.partial(dec.decode_step, cfg), donate_argnums=(1,))
+    c = step.lower(params, caches, jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip),
+                   jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    hlo = c.as_text()
+    k = caches[0][0]["k"]
+    assert k.shape == (16, B, capacity, 16, 128)
+    stack = f"bf16[{','.join(map(str, k.shape))}]"
+    made = _buffers_of_shape(hlo, stack)
+    assert made and all(ops == ("dynamic-update-slice",) for ops, _ in made), made
+    for layer in (k.shape[1:], (1,) + k.shape[1:]):
+        slice_ = f"bf16[{','.join(map(str, layer))}]"
+        assert not _buffers_of_shape(hlo, slice_), slice_
+    assert c.memory_analysis().temp_size_in_bytes < 2 ** 30

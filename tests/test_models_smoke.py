@@ -2,6 +2,7 @@
 step on CPU; shapes + finiteness; decode-vs-forward consistency (the
 strongest correctness property a causal LM stack offers)."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,32 @@ def test_decode_matches_forward(arch):
         lg, caches = decode_step(cfg, params, caches, tokens[:, t : t + 1], jnp.int32(t))
         errs.append(np.abs(np.asarray(lg) - np.asarray(logits[:, t])).max())
     assert max(errs) < 0.15, f"decode diverges from forward: {errs}"
+
+
+@pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_jitted_donated_decode_matches_forward(arch):
+    """decode_step jitted with the caches donated, as launch/serve.py runs it:
+    each step writes the caches in place, so a fault in what the step reads
+    against what it overwrites shows here and not in the eager test.  A
+    sliding window is cut to 4 so its ring buffer wraps twice."""
+    cfg = smoke_config(arch)
+    if cfg.is_moe:
+        cfg = dataclasses.replace(cfg, capacity_factor=8.0)
+    if cfg.window:
+        cfg = dataclasses.replace(cfg, window=4)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens, fr = _inputs(cfg)
+    logits, _ = forward(cfg, params, tokens, frontend=fr)
+    P, S = 6, tokens.shape[1]
+    _, caches = prefill(cfg, params, tokens[:, :P], frontend=fr, capacity=S)
+    step = jax.jit(functools.partial(decode_step, cfg), donate_argnums=(1,))
+    errs = []
+    for t in range(P, S):
+        donated = jax.tree_util.tree_leaves(caches)
+        lg, caches = step(params, caches, tokens[:, t : t + 1], jnp.int32(t))
+        assert all(a.is_deleted() for a in donated)
+        errs.append(np.abs(np.asarray(lg) - np.asarray(logits[:, t])).max())
+    assert max(errs) < 0.15, f"jitted decode diverges from forward: {errs}"
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)

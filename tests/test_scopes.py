@@ -34,9 +34,10 @@ EXPECTED = {
         r"/prefill/layers/.*/attn/kv_cache/", r"/prefill/layers/.*/mlp/",
         r"/prefill/lm_head/"],
     ("olmo-1b", "decode"): [
-        r"/decode/embed/", r"/decode/layers/.*/attn/kv_cache/dynamic_update_slice",
-        r"/decode/layers/.*/attn/[^k]", r"/decode/layers/.*/mlp/", r"/decode/lm_head/",
-        # the scan's stacking of the layers' caches
+        r"/decode/embed/", r"/decode/layers/attn/kv_cache/dynamic_update_slice",
+        r"/decode/layers/.*/attn/kv_cache/", r"/decode/layers/.*/attn/[^k]",
+        r"/decode/layers/.*/mlp/", r"/decode/lm_head/",
+        # the scan's stacking of the layers' new tokens, written after it
         r"/decode/layers/while/body/dynamic_update_slice"],
     ("olmo-1b", "train"): [
         r"/train_step/jvp\(loss\)/embed/", r"/train_step/jvp\(loss\)/layers/while/",
