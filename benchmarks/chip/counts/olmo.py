@@ -51,7 +51,6 @@ def kernels(conf: dict, batch: int, prompt_len: int) -> dict:
     in bfloat16 once each."""
     d, H, G, dh, ff, V, L = _dims(conf)
     return {"flash_attention": {
-        "result_prefix": f"bf16[{batch * H},{prompt_len},{dh}]",
         "calls": L,
         "flops": batch * attention_flops(conf, prompt_len, 1) // L,
         "bytes": 2 * batch * prompt_len * dh * (2 * H + 2 * G),

@@ -35,7 +35,6 @@ def kernels(conf: dict, batch: int, prompt_len: int) -> dict:
     state (float32) written, once each."""
     d, H, K, ff, V, L = _dims(conf)
     return {"wkv6": {
-        "result_prefix": f"(bf16[{batch * H},{prompt_len},{K}]",
         "calls": L,
         "flops": batch * prompt_len * wkv_flops_per_token(conf),
         "bytes": batch * prompt_len * d * (2 + 2 + 2 + 4 + 2) + batch * H * K * K * 4,

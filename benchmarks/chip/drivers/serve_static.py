@@ -49,6 +49,10 @@ class Batch:
     stamps: np.ndarray  # (N,) host clock when each token was read back
 
 
+# the mix's sizes in the CPU tests (smoke.shrink)
+SMALL = dict(batch=4, prompt_len=32, new_tokens=6, check_sequences=4, check_block=2)
+
+
 class Driver:
     SPANS = ("serve.prefill", "serve.decode_step", "serve.readback")
 

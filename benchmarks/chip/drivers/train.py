@@ -36,6 +36,8 @@ import refmath
 
 FIRST_STEPS = 3
 TRACE_STEPS = 4
+# the mix's sizes in the CPU tests (smoke.shrink)
+SMALL = dict(batch=2, seq_len=32, feed_batches=6, mean_doc_len=8)
 
 
 class Driver:

@@ -9,9 +9,24 @@ of its own under this directory, found by the names ``BENCHMARK.json`` gives:
     limits/<cell>.json        the limit of each number ``correct`` compares
     drivers/<driver>.py       one per kind of traffic, with the names of the
                               profiler spans it wraps its calls in (``SPANS``)
+                              and the small sizes of its mix (``SMALL``)
     metrics/<metric>.py       one reader per per-layer metric
-    reference/<name>.py       plain float32 reference and seeded weights
+    reference/<name>.py       plain float32 reference, seeded weights, and the
+                              small widths of the CPU tests (``SMALL``)
     counts/<name>.py          operations and bytes computed from shapes
+
+A new configuration brings its file, its reference and counts, its limits
+and traffic; its file says everything the program needs (``program_config``):
+
+    published keys            those of ``_PROGRAM_FIELDS`` map one to one
+    "program": {...}          fields of the program's ``ModelConfig`` as they
+                              are run: the experts held on this chip
+                              (``n_experts``, also in ``reduced``), ``top_k``,
+                              the experts' ``d_ff``, ``window``, ...
+    "groups": [{...}, ...]    the layer layout, each entry a ``LayerGroup``
+                              (``pattern`` of the program's layer kinds,
+                              ``count``); without it, the arch's first group
+                              repeated to ``num_hidden_layers``
 
 So a later cell, configuration or metric is new files and new entries, and
 no edit here.
@@ -118,15 +133,42 @@ _PROGRAM_FIELDS = {
 
 def program_config(conf: dict):
     """The program's ModelConfig for ``conf``: its repo arch, with every
-    size the file states.  The file is the configuration as it is run."""
+    size the file states.  The file is the configuration as it is run.
+
+    A ``"program"`` field that is no field of ``ModelConfig``, or that
+    disagrees with a published key mapped to the same field, is an error,
+    and so is a ``"groups"`` list whose layers do not add up to
+    ``num_hidden_layers``.  One field may differ: where the run has experts,
+    ``d_ff`` is their width and ``intermediate_size`` may state the dense
+    layers' width beside it."""
     from repro.configs import get_config
+    from repro.configs.base import LayerGroup, ModelConfig
 
     base = get_config(conf["arch"])
     fields = {f: conf[k] for k, f in _PROGRAM_FIELDS.items() if k in conf}
-    group = base.groups[0]
-    fields["groups"] = (dataclasses.replace(
-        group, count=conf["num_hidden_layers"] // len(group.pattern)),)
-    return dataclasses.replace(base, **fields)
+    program = conf.get("program", {})
+    known = {f.name for f in dataclasses.fields(ModelConfig)} - {"groups"}
+    if set(program) - known:
+        raise ValueError(f"{conf['name']}: {sorted(set(program) - known)} in \"program\" "
+                         f"are no fields of ModelConfig (the layout is \"groups\")")
+    experts = program.get("n_experts", base.n_experts) > 0
+    clash = sorted(f for f, v in program.items() if f in fields and fields[f] != v
+                   and not (f == "d_ff" and experts))
+    if clash:
+        raise ValueError(f"{conf['name']}: \"program\" disagrees with the published keys "
+                         f"on {clash}")
+    fields.update(program)
+    if "groups" in conf:
+        groups = tuple(LayerGroup(**dict(g, pattern=tuple(g["pattern"]))) for g in conf["groups"])
+        layers = sum(g.n_layers for g in groups)
+        if layers != conf["num_hidden_layers"]:
+            raise ValueError(f"{conf['name']}: \"groups\" hold {layers} layers, "
+                             f"num_hidden_layers is {conf['num_hidden_layers']}")
+    else:
+        group = base.groups[0]
+        groups = (dataclasses.replace(
+            group, count=conf["num_hidden_layers"] // len(group.pattern)),)
+    return dataclasses.replace(base, groups=groups, **fields)
 
 
 class CompileCounter:

@@ -31,6 +31,9 @@ import jax.numpy as jnp
 from refmath import layernorm, mm, normal
 
 LN_EPS = 1e-5
+# widths of the CPU tests (smoke.shrink, test_reference.py)
+SMALL = dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
+             head_dim=16, intermediate_size=128, vocab_size=256)
 
 
 def dims(conf: dict):

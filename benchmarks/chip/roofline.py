@@ -9,7 +9,7 @@ def share(view, kernel: str):
     k = view.facts.get("kernels", {}).get(kernel)
     if k is None:
         return None
-    ops = view.trace.ops(opcode="custom-call", result_prefix=k["result_prefix"])
+    ops = view.trace.ops(kernel=kernel)
     if not ops:
         return None
     calls = len(ops) / len(view.trace.chips)

@@ -174,10 +174,7 @@ class Op:
     @property
     def kernel(self) -> Optional[str]:
         """The Pallas kernel's name, for a named kernel's custom call."""
-        base = self.name.rsplit(".", 1)[0]
-        if self.opcode != "custom-call" or base == "custom-call" or base.startswith("_unknown_"):
-            return None
-        return base
+        return trace_reduce.kernel_name(self.name, self.opcode)
 
     def named(self, skip: Sequence[str] = ()) -> bool:
         """Under a scope below the root that is not in ``skip``, or a named kernel."""

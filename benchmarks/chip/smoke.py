@@ -1,28 +1,33 @@
 """Small widths and sizes for running the harness on the CPU in tests.
 
 ``shrink(cell)`` keeps a cell's files and changes only sizes, so every
-driver, reference and metric file runs as it would on the chip."""
+driver, reference and metric file runs as it would on the chip.  The sizes
+belong to the files they shrink: a configuration's reference module and a
+mix's driver each define ``SMALL``."""
 from __future__ import annotations
 
 import dataclasses
 
-WIDTHS = {
-    "olmo": dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
-                 num_key_value_heads=4, head_dim=16, intermediate_size=128, vocab_size=256),
-    "rwkv6": dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
-                  num_key_value_heads=4, head_size=16, intermediate_size=128,
-                  vocab_size=256),
-}
-SIZES = {
-    "serve_static": dict(batch=4, prompt_len=32, new_tokens=6, check_sequences=4, check_block=2),
-    "train": dict(batch=2, seq_len=32, feed_batches=6, mean_doc_len=8),
-}
+import harness
+
+
+def small_config(conf: dict) -> dict:
+    """``conf`` at its reference's ``SMALL`` widths.  ``SMALL["program"]``
+    is merged field by field into the file's ``"program"``, so no width the
+    file sets there reaches a CPU test; ``SMALL["groups"]`` replaces the
+    layer layout.  A configuration with no reference stays as it is."""
+    if "reference" not in conf:
+        return conf
+    small = harness.load_module("reference", conf["reference"]).SMALL
+    out = dict(conf, **small)
+    if "program" in conf or "program" in small:
+        out["program"] = dict(conf.get("program", {}), **small.get("program", {}))
+    return out
 
 
 def shrink(cell):
-    config = dict(cell.config, **WIDTHS[cell.config["reference"]])
-    traffic = dict(cell.traffic, **SIZES[cell.traffic["driver"]])
-    return dataclasses.replace(cell, config=config, traffic=traffic)
+    traffic = dict(cell.traffic, **harness.load_module("drivers", cell.traffic["driver"]).SMALL)
+    return dataclasses.replace(cell, config=small_config(cell.config), traffic=traffic)
 
 
 def use(harness, monkeypatch) -> None:

@@ -26,12 +26,53 @@ def test_every_name_has_its_files():
         assert harness.load_json(harness.CHECKOUT / c["file"])["name"] == c["name"]
     for w in BENCH["workloads"]:
         cell = harness.cell(w["name"])
-        harness.load_module("drivers", cell.traffic["driver"])
-        harness.load_module("reference", cell.config["reference"])
+        assert harness.load_module("drivers", cell.traffic["driver"]).SMALL
+        assert harness.load_module("reference", cell.config["reference"]).SMALL
         assert set(cell.limits) and all("limit" in v for v in cell.limits.values())
         assert cell.end_to_end and cell.per_layer
     for m in BENCH["per_layer"]:
         assert callable(harness.load_module("metrics", m["name"]).read)
+
+
+def config_file(name):
+    return harness.load_json(HERE / "configs" / f"{name}.json")
+
+
+def test_program_config_of_the_published_files():
+    """Field for field the ModelConfig these two files gave before they
+    could state a layout or program fields: the arch's first group repeated
+    to num_hidden_layers, and the mapped published sizes."""
+    from repro.configs.base import LayerGroup, ModelConfig
+
+    assert harness.program_config(config_file("olmo-1b")) == ModelConfig(
+        name="olmo-1b", family="dense", d_model=2048, n_heads=16, n_kv_heads=16, d_ff=8192,
+        vocab_size=50304, groups=(LayerGroup(pattern=("attn",), count=16),), head_dim=128,
+        n_experts=0, top_k=0, capacity_factor=1.25, window=0, attn_softcap=0.0,
+        logit_softcap=0.0, rope_theta=10000.0, pos="rope", norm="nonparam_ln", act="silu",
+        gated=True, post_norms=False, tie_embeddings=True, encoder_layers=0, frontend_tokens=0,
+        frontend_dim=0, rwkv_head_dim=64, wkv_chunk=32, lru_width=0, conv_width=4,
+        dtype="bfloat16")
+    assert harness.program_config(config_file("rwkv6-1.6b")) == ModelConfig(
+        name="rwkv6-1.6b", family="ssm", d_model=2048, n_heads=32, n_kv_heads=32, d_ff=7168,
+        vocab_size=65536, groups=(LayerGroup(pattern=("rwkv",), count=24),), head_dim=None,
+        n_experts=0, top_k=0, capacity_factor=1.25, window=0, attn_softcap=0.0,
+        logit_softcap=0.0, rope_theta=10000.0, pos="none", norm="layernorm", act="silu",
+        gated=True, post_norms=False, tie_embeddings=False, encoder_layers=0, frontend_tokens=0,
+        frontend_dim=0, rwkv_head_dim=64, wkv_chunk=32, lru_width=0, conv_width=4,
+        dtype="bfloat16")
+
+
+@pytest.mark.parametrize("extra,message", [
+    ({"program": {"n_expert": 8}}, "no fields of ModelConfig"),
+    ({"program": {"groups": []}}, "no fields of ModelConfig"),
+    ({"program": {"d_model": 1024}}, "disagrees with the published keys on ['d_model']"),
+    ({"program": {"d_ff": 4096}}, "disagrees with the published keys on ['d_ff']"),
+    ({"groups": [{"pattern": ["attn"], "count": 15}]}, "hold 15 layers, num_hidden_layers is 16"),
+])
+def test_program_config_refuses_what_it_cannot_run(extra, message):
+    with pytest.raises(ValueError) as e:
+        harness.program_config(dict(config_file("olmo-1b"), **extra))
+    assert message in str(e.value)
 
 
 def test_without_a_tpu_no_result(capsys):
@@ -97,6 +138,127 @@ def test_a_new_cell_is_new_files_only(tmp_path):
     out = json.loads(res.stdout.strip().splitlines()[-1])
     assert out["correct"] is True and out["attempted"] >= 2
     assert set(out["metrics"]) == {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}
+
+
+# A configuration on the repo's mixtral-8x22b arch, every layer sparse as the
+# program has it: one global attention layer, then two sliding-window ones,
+# 4 of the arch's 8 experts held here, experts 512 wide beside a dense width
+# of 1024.  Its reference is the program's own jnp forward: the test is of
+# the harness's plumbing, not of a model.
+NEW_CONFIG = {
+    "name": "moe-two-groups", "source": "test", "arch": "mixtral-8x22b",
+    "reference": "moe_two_groups", "num_hidden_layers": 3, "hidden_size": 256,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 64,
+    "intermediate_size": 1024, "vocab_size": 1024, "rope_theta": 1e6,
+    "groups": [{"pattern": ["attn"], "count": 1}, {"pattern": ["local"], "count": 2}],
+    "program": {"n_experts": 4, "top_k": 2, "d_ff": 512, "window": 128},
+    "reduced": ["num_hidden_layers", "n_experts"],
+}
+NEW_REFERENCE = '''
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+import harness
+
+SMALL = dict(num_hidden_layers=3, hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+             head_dim=16, intermediate_size=128, vocab_size=256,
+             groups=[{"pattern": ["attn"], "count": 1}, {"pattern": ["local"], "count": 2}],
+             program={"d_ff": 32, "window": 8})
+
+
+def _cfg(conf, dtype):
+    return dataclasses.replace(harness.program_config(conf), dtype=jnp.dtype(dtype).name)
+
+
+def weights(conf, key, dtype=jnp.bfloat16):
+    from repro.models import init_params
+
+    return init_params(_cfg(conf, dtype), key)
+
+
+def logits(conf, params, tokens, start, mode="f32"):
+    from repro.models.transformer import forward
+
+    params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    return forward(_cfg(conf, jnp.float32), params, tokens)[0][:, start:, :conf["vocab_size"]]
+'''
+NEW_COUNTS = '''
+import harness
+
+
+def serve_flops(conf, batch, prompt_len, new_tokens):
+    active = harness.program_config(conf).active_param_count()
+    return 2 * batch * (prompt_len + new_tokens - 1) * active
+
+
+def kernels(conf, batch, prompt_len):
+    return {}
+'''
+
+
+def test_program_config_of_groups_and_program_fields():
+    """A layout of two groups; the experts' width beside the dense width."""
+    from repro.configs.base import LayerGroup
+
+    cfg = harness.program_config(NEW_CONFIG)
+    assert cfg.groups == (LayerGroup(("attn",), 1), LayerGroup(("local",), 2))
+    assert (cfg.n_experts, cfg.top_k, cfg.d_ff, cfg.window) == (4, 2, 512, 128)
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (256, 4, 2, 64)
+    same = dict(NEW_CONFIG, program=dict(NEW_CONFIG["program"], d_model=256))
+    assert harness.program_config(same) == cfg  # a field that agrees is no error
+
+
+def test_a_new_configuration_is_new_files_only(tmp_path):
+    """A copy of the benchmark with a configuration of two layer groups and
+    program fields, its reference with its SMALL widths, its counts, limits,
+    traffic and cell, each added as new files and entries: the cell runs
+    through run.main, shrunk by smoke.shrink, with no code edited."""
+    root = tmp_path / "checkout"
+    chip = root / "benchmarks" / "chip"
+    shutil.copytree(HERE, chip, ignore=shutil.ignore_patterns(".runs", "__pycache__"))
+    name = "moe-two-groups.serve.moe"
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({"name": "moe-two-groups", "source": "test", "why": "test",
+                             "file": "benchmarks/chip/configs/moe-two-groups.json",
+                             "reduced": NEW_CONFIG["reduced"]})
+    bench["workloads"].append({"name": name, "config": "moe-two-groups", "traffic": "serve.moe",
+                               "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "rwkv6-1.6b.serve.longprompt" in m.get("workloads", []) and "roofline" not in m["name"]:
+            m["workloads"].append(name)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    (chip / "configs" / "moe-two-groups.json").write_text(json.dumps(NEW_CONFIG))
+    (chip / "reference" / "moe_two_groups.py").write_text(NEW_REFERENCE)
+    (chip / "counts" / "moe_two_groups.py").write_text(NEW_COUNTS)
+    (chip / "traffic" / "serve.moe.json").write_text(json.dumps(
+        {"driver": "serve_static", "batch": 8, "prompt_len": 512, "new_tokens": 64,
+         "ahead_steps": 2, "check_sequences": 8, "check_block": 2}))
+    (chip / "limits" / f"{name}.json").write_text(json.dumps({"max_gap": {"limit": 0.25}}))
+    code = (
+        "import sys, jax, harness, smoke, run\n"
+        "from repro.configs.base import LayerGroup\n"
+        "real = harness.cell\n"
+        "harness.cell = lambda n: smoke.shrink(real(n))\n"
+        f"cell = harness.cell({name!r})\n"
+        "cfg = harness.program_config(cell.config)\n"
+        "assert cfg.groups == (LayerGroup(('attn',), 1), LayerGroup(('local',), 2)), cfg\n"
+        "assert (cfg.d_model, cfg.n_experts, cfg.top_k, cfg.d_ff, cfg.window) == "
+        "(64, 4, 2, 32, 8), cfg\n"
+        "assert (cell.traffic['batch'], cell.traffic['prompt_len']) == (4, 32), cell.traffic\n"
+        f"sys.exit(run.main(['--workload', {name!r}, '--seed', str(2 ** 31 + 78), "
+        "'--seconds', '1'], devices=jax.devices()[:1]))\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(chip), str(harness.CHECKOUT / "src")]))
+    res = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True, text=True,
+                         timeout=600, cwd=root)
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["failed"] == 0 and out["attempted"] >= 4
+    assert set(out["metrics"]) == {"serve_tokens_per_s", "itl_p95_ms", "setup_s"}
+    assert out["checks"]["max_gap"]["value"] <= 0.25
 
 
 ECHO_DRIVER = '''

@@ -11,22 +11,21 @@ import pytest
 
 import harness
 import refmath
+import smoke
 
-SMALL = {
-    "olmo": dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
-                 num_key_value_heads=4, head_dim=16, intermediate_size=128, vocab_size=256),
-    "rwkv6": dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
-                  num_key_value_heads=4, head_size=16, intermediate_size=128,
-                  vocab_size=256),
-}
-CONFIGS = {"olmo": "olmo-1b", "rwkv6": "rwkv6-1.6b"}
+# every configuration of BENCHMARK.json that names a reference, by that name
+CONFIGS = [pytest.param(conf, id=conf["reference"]) for conf in (
+    harness.load_json(harness.CHECKOUT / c["file"])
+    for c in harness.load_json(harness.CHECKOUT / "BENCHMARK.json")["configs"])
+    if "reference" in conf]
 
 
-def small(ref_name):
-    conf = dict(harness.load_json(harness.HERE / "configs" / f"{CONFIGS[ref_name]}.json"))
-    conf.update(SMALL[ref_name])
+def small(conf):
+    """``conf`` at its reference's ``SMALL`` widths, the program's
+    ModelConfig of it in float32, and the reference's float32 weights."""
+    conf = smoke.small_config(conf)
     cfg = dataclasses.replace(harness.program_config(conf), dtype="float32")
-    params = harness.load_module("reference", ref_name).weights(
+    params = harness.load_module("reference", conf["reference"]).weights(
         conf, refmath.seed_key(2 ** 31 + 3), dtype=jnp.float32)
     return conf, cfg, params
 
@@ -36,33 +35,33 @@ def tokens(conf, n, T, seed=0):
                        jnp.int32)
 
 
-@pytest.mark.parametrize("ref_name", ["olmo", "rwkv6"])
-def test_weights_have_the_program_layout(ref_name):
+@pytest.mark.parametrize("conf", CONFIGS)
+def test_weights_have_the_program_layout(conf):
     from repro.models import init_params
 
-    conf, cfg, params = small(ref_name)
+    conf, cfg, params = small(conf)
     want = jax.eval_shape(functools.partial(init_params, cfg), jax.random.PRNGKey(0))
     assert harness.layout(params) == harness.layout(want)
 
 
-@pytest.mark.parametrize("ref_name", ["olmo", "rwkv6"])
-def test_forward(ref_name):
+@pytest.mark.parametrize("conf", CONFIGS)
+def test_forward(conf):
     from repro.models.transformer import forward
 
-    conf, cfg, params = small(ref_name)
-    ref = harness.load_module("reference", ref_name)
+    conf, cfg, params = small(conf)
+    ref = harness.load_module("reference", conf["reference"])
     toks = tokens(conf, 2, 40)
     got = forward(cfg, params, toks)[0][..., : conf["vocab_size"]]
     want = ref.logits(conf, params, toks, 0)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4 * float(jnp.abs(want).max()))
 
 
-@pytest.mark.parametrize("ref_name", ["olmo", "rwkv6"])
-def test_prefill_then_decode(ref_name):
+@pytest.mark.parametrize("conf", CONFIGS)
+def test_prefill_then_decode(conf):
     from repro.models import decode as dec
 
-    conf, cfg, params = small(ref_name)
-    ref = harness.load_module("reference", ref_name)
+    conf, cfg, params = small(conf)
+    ref = harness.load_module("reference", conf["reference"])
     P, N = 32, 6
     toks = tokens(conf, 2, P + N)
     want = ref.logits(conf, params, toks, P - 1)  # (2, N + 1, V)
@@ -81,8 +80,8 @@ def test_three_optimizer_steps():
     from repro.models.steps import train_step
     from repro.optim import adamw as program_adamw
 
-    conf, cfg, params = small("olmo")
-    ref = harness.load_module("reference", "olmo")
+    conf, cfg, params = small(harness.cell("olmo-1b.train.s2048").config)
+    ref = harness.load_module("reference", conf["reference"])
     adamw = harness.load_module("reference", "adamw")
     hp = harness.load_json(harness.HERE / "traffic" / "train.s2048.json")
     hp = dict(hp, warmup_steps=2, total_steps=10, grad_clip=0.5)  # both schedule branches, clipping

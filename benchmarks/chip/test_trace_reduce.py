@@ -52,8 +52,10 @@ def test_idle_is_named_by_what_the_host_did(trace):
 
 
 def test_kernel_ops_by_opcode_and_shape(trace):
-    kernel = trace.ops(opcode="custom-call", result_prefix="bf16[2,256,128]")
-    assert len(kernel) == 3 and all(o.dur > 0 for o in kernel)
+    kernel = trace.ops(opcode="custom-call")
+    assert len(kernel) == 3 and all(o.dur > 0 and o.result.startswith("bf16[2,256,128]")
+                                    for o in kernel)
+    assert trace.ops(kernel="flash_attention") == []  # recorded before kernels had names
     assert trace.module_seconds("serve.prefill") > sum(o.dur for o in kernel)
     assert trace.top_ops(1)[0][0].startswith("serve.prefill:custom-call")
 
@@ -66,6 +68,26 @@ def test_kernel_ops_by_opcode_and_shape(trace):
 ])
 def test_parse_op(text, want):
     assert trace_reduce._parse_op(text) == want
+
+
+def test_top_ops_name_a_named_kernel():
+    """A custom call named by ``pallas_call(name=...)`` is labelled by that
+    name; an unnamed one, and every other op, by opcode and result type."""
+    def op(name, opcode, result, dur):
+        return trace_reduce.Op(name, opcode, result, 0.0, dur, "serve.prefill")
+
+    ops = [op("wkv6.6", "custom-call", "(bf16[64,32,64]{2,1,0}, f32[64,64,64]{2,1,0})", 3.0),
+           op("wkv6.6", "custom-call", "(bf16[64,32,64]{2,1,0}, f32[64,64,64]{2,1,0})", 3.0),
+           op("_unknown_.1", "custom-call", "bf16[2,256,128]{2,1,0}", 2.0),
+           op("custom-call.3", "custom-call", "bf16[2,2048]{1,0}", 1.5),
+           op("fusion.3", "fusion", "bf16[8,128]{1,0}", 1.0)]
+    trace = trace_reduce.Trace([trace_reduce.Chip("/device:TPU:0", ops, [], None)], [], (0, 10))
+    assert trace.top_ops() == [["serve.prefill:wkv6", 6.0],
+                               ["serve.prefill:custom-call bf16[2,256,128]", 2.0],
+                               ["serve.prefill:custom-call bf16[2,2048]", 1.5],
+                               ["serve.prefill:fusion bf16[8,128]", 1.0]]
+    assert [o.kernel for o in ops] == ["wkv6", "wkv6", None, None, None]
+    assert trace.ops(kernel="wkv6") == ops[:2]
 
 
 def test_union_merges_overlaps():

@@ -36,14 +36,28 @@ OTHER = "host:other"
 _HLO = re.compile(r"^%?(?P<name>[^ ]+) = (?P<type>\([^=]*?\)|[^ ]+) (?P<opcode>[a-z][a-z0-9_-]*)\(")
 
 
+def kernel_name(name: str, opcode: str) -> Optional[str]:
+    """The Pallas kernel's name (``pallas_call(name=...)``), which a named
+    kernel's custom call takes as its op name (``wkv6.6``): the op name
+    without its ``.N``.  None for other ops and unnamed custom calls."""
+    base = name.rsplit(".", 1)[0]
+    if opcode != "custom-call" or base == "custom-call" or base.startswith("_unknown_"):
+        return None
+    return base
+
+
 @dataclasses.dataclass
 class Op:
-    name: str  # HLO instruction name, e.g. "fusion.3"
+    name: str  # HLO instruction name, e.g. "fusion.3", "wkv6.6"
     opcode: str  # e.g. "fusion", "custom-call"
     result: str  # result type, e.g. "bf16[8,512,128]{...}"
     start: float  # seconds, host clock
     dur: float
     span: str  # benchmark span that dispatched its program
+
+    @property
+    def kernel(self) -> Optional[str]:
+        return kernel_name(self.name, self.opcode)
 
 
 @dataclasses.dataclass
@@ -92,18 +106,19 @@ class Trace:
         return tot / max(len(self.chips), 1)
 
     def ops(self, span: Optional[str] = None, opcode: Optional[str] = None,
-            result_prefix: Optional[str] = None) -> List[Op]:
+            kernel: Optional[str] = None) -> List[Op]:
         return [o for c in self.chips for o in c.ops
                 if (span is None or o.span == span)
                 and (opcode is None or o.opcode == opcode)
-                and (result_prefix is None or o.result.startswith(result_prefix))]
+                and (kernel is None or o.kernel == kernel)]
 
     def top_ops(self, n: int = 10) -> List[List]:
-        """The device ops that took most time (summed over runs), per chip."""
+        """The device ops that took most time (summed over runs), per chip:
+        a named kernel by its name, any other op by opcode and result type."""
         agg: Dict[str, float] = defaultdict(float)
         for c in self.chips:
             for o in c.ops:
-                agg[f"{o.span}:{o.opcode} {o.result.split('{')[0]}"] += o.dur
+                agg[f"{o.span}:{o.kernel or o.opcode + ' ' + o.result.split('{')[0]}"] += o.dur
         k = max(len(self.chips), 1)
         return [[name, t / k] for name, t in sorted(agg.items(), key=lambda kv: -kv[1])[:n]]
 
